@@ -19,11 +19,13 @@ import argparse
 import json
 from pathlib import Path
 
+from repro.launch.mesh import peaks
+
 ART = Path(__file__).resolve().parent / "artifacts"
 DRY = ART / "dryrun"
 
-HW = {"peak_flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9,
-      "hbm_bytes": 16 * 1024**3}
+# the dry-run projects the production mesh (launch/mesh.py), a TPU v5e pod
+HW = peaks("TPU v5 lite")
 
 
 # (seq_len, global_batch) per shape — tokens are recomputed here so stale
@@ -47,14 +49,14 @@ def model_flops_per_device(rec) -> float:
 
 def analyze_record(rec) -> dict:
     c = rec["cost"]
-    t_compute = c["flops_per_device"] / HW["peak_flops"]
+    t_compute = c["flops_per_device"] / HW["peak_flops_bf16"]
     t_memory = c["bytes_per_device"] / HW["hbm_bw"]
     t_coll = c["collective_bytes_per_device"] / HW["ici_bw"]
     terms = {"compute": t_compute, "memory": t_memory, "collective": t_coll}
     dominant = max(terms, key=terms.get)
     mf = model_flops_per_device(rec)
     useful_ratio = mf / max(c["flops_per_device"], 1e-9)
-    t_model = mf / HW["peak_flops"]
+    t_model = mf / HW["peak_flops_bf16"]
     bound = max(terms.values())
     return {
         "arch": rec["arch"], "shape": rec["shape"], "mesh": rec["mesh"],

@@ -24,6 +24,8 @@ def main() -> None:
                     help="comma-separated subset of " + ",".join(SUITES))
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else set(SUITES)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from benchmarks import (table2_training, fig6_scalability, fig7_sampling,
                             fig8_parallelism, fig_scaleout, fig_halo,

@@ -10,18 +10,19 @@ Every consumer of node features goes through ONE interface:
     when a cache is configured, a direct host-store gather otherwise.
     Bit-exact with the pre-plane code (the regression anchor).
   * ``DeviceFeaturePlane`` — the cache table and the slot map (device map)
-    live as jax device arrays; a batch fetch looks slots up on device and
-    gathers resident rows with the Pallas kernel
-    (``kernels/gather.cache_gather``), falling back to the host feature
-    store for misses.  Accounting, FIFO insertion and resize semantics are
-    delegated to the SAME ``FeatureCache`` bookkeeping, so the two planes
-    are bit-exact and stats-exact on the same request stream.
+    live as jax device arrays; a batch fetch gathers resident rows on
+    device — with the Pallas kernel (``kernels/gather.cache_gather``) when
+    the plane's device is a TPU, the jnp gather elsewhere — and reads
+    misses from the host feature store.  Accounting, FIFO insertion and
+    resize semantics are delegated to the SAME ``FeatureCache``
+    bookkeeping, so the two planes are bit-exact and stats-exact on the
+    same request stream.
 
 ``make_feature_plane`` picks the backend from
 ``GNNConfig.sampling_device`` (``cpu | device | auto`` — auto probes
-``jax.devices()`` and chooses the device plane only when a non-CPU
-accelerator is attached; the device plane still RUNS on CPU hosts through
-the kernel's interpret mode, which is what the parity tests exercise).
+``jax.devices()`` and chooses the device plane only when a TPU is
+attached, and logs its choice; the device plane still RUNS on CPU hosts,
+which is what the parity tests exercise).
 
 Reconfiguration contract (the autotune controller's live swaps):
 
@@ -35,6 +36,7 @@ Reconfiguration contract (the autotune controller's live swaps):
 """
 from __future__ import annotations
 
+import logging
 import threading
 from typing import Optional
 
@@ -44,9 +46,11 @@ from repro.core.cache import FeatureCache
 from repro.graph.storage import Graph
 from repro.kernels.pad_plan import bucket_plan
 
+log = logging.getLogger(__name__)
+
 # device-plane gather is issued in bounded row chunks: each distinct padded
-# shape costs one jit trace (expensive in interpret mode), so chunking plus
-# pow2 bucketing of the tail keeps the set of compiled shapes small and
+# shape costs one jit trace and compile, so chunking plus pow2 bucketing
+# of the tail keeps the set of compiled shapes small and
 # independent of the batch-size schedule.  4096 covers the paper's batch
 # regime in ONE dispatch — per-chunk dispatch overhead, not gather
 # bandwidth, dominates the device plane's fixed cost
@@ -122,7 +126,7 @@ def _host_pack(enc, aux):
     return _host_pack_jit(enc, aux)
 
 
-def _run_fused(enc, neigh_idx, table, aux, use_pallas: bool, interpret: bool,
+def _run_fused(enc, neigh_idx, table, aux, use_pallas: bool,
                mode: str = "mean"):
     """Bucket the fused gather+aggregate inputs to pow2 row counts (jit
     retraces stay bounded across the batch-size schedule) and slice the
@@ -142,8 +146,7 @@ def _run_fused(enc, neigh_idx, table, aux, use_pallas: bool, interpret: bool,
     aux_p[:len(aux)] = aux
     h, a = gather_aggregate(jnp.asarray(enc_p), jnp.asarray(idx_p),
                             jnp.asarray(table), jnp.asarray(aux_p),
-                            mode=mode, use_pallas=use_pallas,
-                            interpret=interpret)
+                            mode=mode, use_pallas=use_pallas)
     return np.asarray(h)[:nd], np.asarray(a)[:nd]
 
 
@@ -202,7 +205,7 @@ class FeaturePlane:
         enc = -np.arange(1, len(ids) + 1, dtype=np.int32)
         table = np.zeros((1, self.graph.feat_dim), np.float32)
         return _run_fused(enc, neigh_idx, table, rows,
-                          use_pallas=False, interpret=False, mode=mode)
+                          use_pallas=False, mode=mode)
 
     def fused_inputs(self, ids: np.ndarray, cap: int):
         """Encoded layer-0 inputs for the all-hop fused train step
@@ -315,25 +318,20 @@ class DeviceFeaturePlane(FeaturePlane):
     ``patch_resident`` scatters only the dirty rows into the live buffers
     (donated, so no second table materializes); a full delete + re-upload
     happens only on reallocation (``resize``/``_alloc``) or when the
-    bounded log was dropped.  ``use_pallas=None`` resolves to the Pallas
-    gather only when a real accelerator is attached — on CPU hosts the
-    jitted pure-jnp reference path is both the fast AND the faithful
-    choice (interpret-mode Pallas is a debugging vehicle, exercised by
-    the kernel tests, not a production configuration).
+    bounded log was dropped.  The mirror lives on ``device`` (default: the
+    first device).  On a TPU the gathers always run the compiled Pallas
+    kernels; on any other device they run the jitted jnp reference (the
+    kernels' interpret mode is exercised by the kernel tests).
     """
 
     backend = "device"
 
     def __init__(self, graph: Graph, cache: Optional[FeatureCache] = None,
-                 use_pallas: Optional[bool] = None,
-                 interpret: Optional[bool] = None,
-                 incremental_sync: bool = True):
+                 incremental_sync: bool = True, device=None):
         super().__init__(graph, cache)
         import jax
-        accel = jax.devices()[0].platform in ("tpu", "gpu")
-        self.use_pallas = use_pallas if use_pallas is not None else accel
-        # interpret mode unless a real accelerator backs the default device
-        self.interpret = interpret if interpret is not None else not accel
+        self.device = device if device is not None else jax.devices()[0]
+        self.use_pallas = self.device.platform == "tpu"
         self.incremental_sync = incremental_sync
         self._dev_table = None
         self._dev_slots = None
@@ -375,8 +373,8 @@ class DeviceFeaturePlane(FeaturePlane):
             for buf in (self._dev_table, self._dev_slots):
                 if buf is not None:
                     buf.delete()
-            self._dev_table = jax.device_put(c.storage)
-            self._dev_slots = jax.device_put(c.device_map)
+            self._dev_table = jax.device_put(c.storage, self.device)
+            self._dev_slots = jax.device_put(c.device_map, self.device)
             self.sync_full_uploads += 1
             self.sync_bytes_uploaded += (c.storage.nbytes
                                          + c.device_map.nbytes)
@@ -430,7 +428,6 @@ class DeviceFeaturePlane(FeaturePlane):
             return self._fetch_locked(ids, c)
 
     def _fetch_locked(self, ids: np.ndarray, c: FeatureCache) -> np.ndarray:
-        import jax.numpy as jnp
         from repro.kernels.gather.ops import cache_gather
         self._ensure_synced()
         n = len(ids)
@@ -451,9 +448,8 @@ class DeviceFeaturePlane(FeaturePlane):
             # pad slots resolve to -1 (a miss) — zero rows, sliced off below
             pad = np.full(mp, -1, dtype=np.int32)
             pad[:m] = slots_np[a:a + m]
-            rows, _ = cache_gather(jnp.asarray(pad), self._dev_table,
-                                   use_pallas=self.use_pallas,
-                                   interpret=self.interpret)
+            rows, _ = cache_gather(pad, self._dev_table,
+                                   use_pallas=self.use_pallas)
             # jax dispatch is async: don't block on the result yet
             pending.append((a, m, rows))
         # double-buffered miss path: gather missed rows from the host
@@ -495,8 +491,7 @@ class DeviceFeaturePlane(FeaturePlane):
             self.gather_dispatches += 1
             self.gather_rows += len(ids)
             out = _run_fused(enc, neigh_idx, self._dev_table, aux,
-                             use_pallas=self.use_pallas,
-                             interpret=self.interpret, mode=mode)
+                             use_pallas=self.use_pallas, mode=mode)
             # same accounting seam as _fetch_locked (stats-exact invariant)
             c.account_fetch(hit, miss_ids)
         return out
@@ -518,6 +513,7 @@ class DeviceFeaturePlane(FeaturePlane):
         c = self.cache
         if c is None or not c.capacity:
             return super().fused_inputs(ids, cap)
+        import jax
         import jax.numpy as jnp
         n = len(ids)
         if n > cap:
@@ -532,7 +528,9 @@ class DeviceFeaturePlane(FeaturePlane):
             enc[:n][~hit] = -np.arange(1, len(miss_ids) + 1, dtype=np.int32)
             aux = self._aux_bufs.get(cap)
             if aux is None:
-                aux = jnp.zeros((cap, self.graph.feat_dim), jnp.float32)
+                aux = jax.device_put(
+                    jnp.zeros((cap, self.graph.feat_dim), jnp.float32),
+                    self.device)
             # pow2-padded miss scatter with out-of-range pad indices
             # (dropped — padded vals rows never land in the buffer), same
             # discipline as the mirror sync; m == 0 rides the minimal
@@ -565,19 +563,23 @@ class DeviceFeaturePlane(FeaturePlane):
 
 
 def make_feature_plane(graph: Graph, cache: Optional[FeatureCache],
-                       sampling_device: str = "cpu") -> FeaturePlane:
+                       sampling_device: str = "cpu",
+                       device=None) -> FeaturePlane:
     """Backend factory for the batch-generation gather stage.
 
-    ``cpu`` → ``HostFeaturePlane``; ``device`` → ``DeviceFeaturePlane``;
-    ``auto`` probes ``jax.devices()`` and picks the device plane only when
-    a real accelerator (TPU/GPU) is attached.
+    ``cpu`` → ``HostFeaturePlane``; ``device`` → ``DeviceFeaturePlane``
+    mirrored on ``device`` (default: the first device); ``auto`` probes
+    ``jax.devices()`` and picks the device plane only when a TPU is
+    attached.
     """
     if sampling_device == "auto":
         import jax
-        has_accel = any(d.platform in ("tpu", "gpu") for d in jax.devices())
-        sampling_device = "device" if has_accel else "cpu"
+        platform = jax.devices()[0].platform
+        sampling_device = "device" if platform == "tpu" else "cpu"
+        log.info("sampling_device=auto: %s feature plane on a %s backend",
+                 sampling_device, platform)
     if sampling_device == "device":
-        return DeviceFeaturePlane(graph, cache)
+        return DeviceFeaturePlane(graph, cache, device=device)
     if sampling_device in ("cpu", "host"):
         return HostFeaturePlane(graph, cache)
     raise ValueError(f"unknown sampling_device: {sampling_device!r} "

@@ -13,8 +13,9 @@ the existing substrate:
     per partition, exactly as on a real device);
   * gradients synchronize through ``distributed/collectives.grad_allreduce``
     under a mesh from ``launch/mesh.make_partition_mesh`` — a real device
-    mesh when the host has one device per partition, a ``HostSimMesh``
-    (identical arithmetic, no topology) on the 1-CPU CI container;
+    mesh when the host has one device per partition (partition p's feature
+    mirror, batch, params copy and gradient step all live on mesh device
+    p), a ``HostSimMesh`` (identical arithmetic, no topology) otherwise;
   * with ``cfg.halo_budget > 0`` each partition's subgraph is augmented
     with its top-k boundary nodes (``PartitionPlan.halo_sets``) and their
     feature rows arrive through ``distributed/collectives.halo_all_to_all``
@@ -42,6 +43,7 @@ from typing import Callable, Dict, List, Optional
 
 import jax
 import numpy as np
+from jax.sharding import Mesh
 
 from repro.configs.gnn import GNNConfig
 from repro.core.cache import FeatureCache
@@ -226,6 +228,9 @@ class MultiPartitionTrainer(TrainerCheckpointMixin, FeatureStreamConsumer):
                                                    method, seed,
                                                    halo_budget=cfg.halo_budget)
         self.mesh = make_partition_mesh(self.plan.parts)
+        # one device per partition on a real mesh; None → default device
+        self.devices = (list(self.mesh.devices.flat)
+                        if isinstance(self.mesh, Mesh) else None)
         self._allreduce = grad_allreduce(self.mesh)
         self._halo_exchange = halo_all_to_all(self.mesh)
         rng = jax.random.PRNGKey(seed)
@@ -351,13 +356,23 @@ class MultiPartitionTrainer(TrainerCheckpointMixin, FeatureStreamConsumer):
                              n_owned=n_owned,
                              cache=cache, weight_fn=weight_fn)
         slot.pipe = Pipeline(sub, cfg, self._slot_train_fn(slot), cache=cache,
-                             weight_fn=weight_fn, seed=self.seed + p)
+                             weight_fn=weight_fn, seed=self.seed + p,
+                             device=self._device(p))
         return slot
+
+    def _device(self, p: int):
+        return self.devices[p] if self.devices is not None else None
 
     def _slot_train_fn(self, slot: PartitionSlot):
         """Per-partition "train" = local gradient computation; the shared
         update is applied after the cross-partition all-reduce."""
         def fn(mb, plane=None):
+            # the gradient step runs on the partition's own device: its
+            # params copy and batch are placed there (the fused inputs
+            # already live there, on the slot plane's mirror)
+            dev = self._device(slot.index)
+            params = (self.params if dev is None
+                      else jax.device_put(self.params, dev))
             hs = slot.halo_stats
             hs.halo_hits += int((mb.input_ids >= slot.n_owned).sum())
             hs.inputs += len(mb.input_ids)
@@ -371,14 +386,15 @@ class MultiPartitionTrainer(TrainerCheckpointMixin, FeatureStreamConsumer):
                 enc0, aux0, table = plane.fused_inputs(mb.input_ids,
                                                        arrays["pads"][0])
                 grads, loss, acc = self._grad_allfused(
-                    self.params, enc0, aux0, table,
+                    params, enc0, aux0, table,
                     arrays["neigh_idxs"], arrays["labels"])
             else:
                 arrays = batch_device_arrays(mb)
-                grads, loss, acc = self._grad(self.params,
-                                              arrays["features"],
-                                              arrays["neigh_idxs"],
-                                              arrays["labels"])
+                inputs = (arrays["features"], arrays["neigh_idxs"],
+                          arrays["labels"])
+                if dev is not None:
+                    inputs = jax.device_put(inputs, dev)
+                grads, loss, acc = self._grad(params, *inputs)
             slot.pending_grads = grads
             return float(loss), float(acc)
         return fn

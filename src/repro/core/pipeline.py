@@ -13,8 +13,9 @@ Three executors over the Algo.-1 stages (sample → batch-generate → train):
 On the host-TPU adaptation workers are threads (numpy sampling releases the
 GIL in the hot gather ops) and the bounded queue doubles as the
 double-buffer: while the device runs step k, workers prepare k+1.  Worker
-failures are tolerated: a failed seed batch is re-issued on a spare sampler
-(fault_tolerance.py provides the same machinery for the LM trainer).
+failures are tolerated: a failed seed batch is logged with its exception
+and re-issued on a spare sampler (fault_tolerance.py provides the same
+machinery for the LM trainer).
 
 The executor is RECONFIGURABLE at an episode boundary (the autotune
 controller's drain → reconfigure → resume contract):
@@ -31,6 +32,7 @@ controller's drain → reconfigure → resume contract):
 from __future__ import annotations
 
 import inspect
+import logging
 import queue
 import threading
 import time
@@ -45,6 +47,8 @@ from repro.core.sampling import NeighborSampler, MiniBatch, seed_loader
 from repro.graph.batch import generate_batch, batch_bytes
 
 _UNSET = object()
+
+log = logging.getLogger(__name__)
 
 
 @dataclass
@@ -111,6 +115,8 @@ class _SampleWorker(threading.Thread):
                 self._count += 1
                 self.out_q.put((idx, seeds, mb))
             except Exception:  # noqa: BLE001 — re-queue the work item
+                log.warning("worker %d failed on batch %d; re-issuing it",
+                            self.wid, idx, exc_info=True)
                 self.heartbeat[self.wid] = -1.0   # mark dead
                 self.out_q.put((idx, seeds, None))
             finally:
@@ -123,14 +129,16 @@ class Pipeline:
     def __init__(self, graph, cfg, train_fn: Callable[[MiniBatch], tuple],
                  cache: Optional[FeatureCache] = None,
                  weight_fn=None, seed: int = 0,
-                 plane: Optional[FeaturePlane] = None):
+                 plane: Optional[FeaturePlane] = None, device=None):
         self.graph, self.cfg = graph, cfg
         self.train_fn = train_fn
         # the feature plane is the ONLY seam batch generation fetches
         # through; `cache` remains the constructor currency (trainers own
-        # the cache object) and the plane wraps it per sampling_device
+        # the cache object) and the plane wraps it per sampling_device,
+        # its device mirror (if any) on `device`
+        self.device = device
         self.plane = plane if plane is not None else make_feature_plane(
-            graph, cache, getattr(cfg, "sampling_device", "cpu"))
+            graph, cache, getattr(cfg, "sampling_device", "cpu"), device)
         self.sampling_device = self.plane.backend
         self.weight_fn = weight_fn
         self.seed = seed
@@ -335,7 +343,8 @@ class Pipeline:
                     or self.sampling_device != self.plane.backend):
                 old_plane = self.plane
                 self.plane = make_feature_plane(self.graph, new_cache,
-                                                self.sampling_device)
+                                                self.sampling_device,
+                                                self.device)
                 self.sampling_device = self.plane.backend
                 # a FeatureStore subscription follows the LIVE plane: the
                 # dead plane detaches (no stale routing, nothing pinned)

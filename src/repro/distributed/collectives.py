@@ -10,8 +10,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
 def _partial_attend(q, k, v, mask):
@@ -54,8 +53,8 @@ def flash_decode_attention(mesh: Mesh, axis: str = "model"):
         return (num / jnp.maximum(den[..., None], 1e-30)).astype(v.dtype)
 
     in_specs = (P(), P(None, axis, None, None), P(None, axis, None, None), P())
-    return shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=P(),
-                     check_rep=False)
+    return jax.shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=P(),
+                         check_vma=False)
 
 
 def grad_allreduce(mesh, axis: str = "part"):
@@ -63,9 +62,11 @@ def grad_allreduce(mesh, axis: str = "part"):
     GNN scale-out, core/multipart.py).
 
     Returns ``fn(trees) -> tree`` averaging a list of identically-structured
-    gradient pytrees, one per partition.  On a real ``Mesh`` each leaf is
-    stacked over ``axis`` and reduced with a shard_map psum (the collective
-    that runs on hardware); on a ``HostSimMesh`` (CI: fewer devices than
+    gradient pytrees, one per partition.  On a real ``Mesh`` tree p lives
+    on mesh device p: each leaf becomes one global array sharded over
+    ``axis`` without leaving its device, is reduced with a shard_map psum
+    (the collective that runs on hardware), and the mean comes back on
+    mesh device 0.  On a ``HostSimMesh`` (CI: fewer devices than
     partitions) the same reduction happens as host-side tree arithmetic —
     bitwise the same mean, no device topology required.
     """
@@ -80,25 +81,35 @@ def grad_allreduce(mesh, axis: str = "part"):
         return host_mean
 
     axis_size = dict(zip(mesh.axis_names, mesh.devices.shape))[axis]
+    devices = list(mesh.devices.flat)
+    sharding = NamedSharding(mesh, P(axis))
 
     def local(x):
         return jax.lax.psum(x, axis) / axis_size
 
     # built ONCE per grad_allreduce call; jit caches per gradient-tree
     # structure, so the per-step cost is a single dispatch, not a retrace
-    reduce_leaf = shard_map(local, mesh=mesh, in_specs=P(axis),
-                            out_specs=P(axis), check_rep=False)
+    reduce_leaf = jax.shard_map(local, mesh=mesh, in_specs=P(axis),
+                                out_specs=P(axis), check_vma=False)
+    tree_mean = jax.jit(lambda stacked: jax.tree.map(reduce_leaf, stacked))
 
-    @jax.jit
-    def tree_mean(stacked):
-        # every shard holds the mean after the psum; take shard 0's copy
-        return jax.tree.map(lambda s: reduce_leaf(s)[0], stacked)
+    def stack_leaf(*xs):
+        shards = [jax.device_put(x, d)[None] for x, d in zip(xs, devices)]
+        return jax.make_array_from_single_device_arrays(
+            (len(xs),) + xs[0].shape, sharding, shards)
+
+    def first_row(s):
+        # every shard holds the mean after the psum; keep device 0's copy
+        shard = next(sh for sh in s.addressable_shards
+                     if sh.device == devices[0])
+        return shard.data[0]
 
     def mesh_mean(trees):
         if len(trees) != axis_size:
             raise ValueError(f"got {len(trees)} gradient trees for a "
                              f"{axis_size}-way '{axis}' mesh axis")
-        return tree_mean(jax.tree.map(lambda *xs: jnp.stack(xs), *trees))
+        stacked = jax.tree.map(stack_leaf, *trees)
+        return jax.tree.map(first_row, tree_mean(stacked))
 
     return mesh_mean
 
@@ -177,13 +188,14 @@ def halo_all_to_all(mesh, axis: str = "part"):
                 rows = send[q][p]
                 buf[p, :len(rows)] = part_feats[q][rows]
             bufs.append(buf)
-        stacked = jnp.stack(bufs)                     # (parts, parts, pad, F)
+        # (parts, parts, pad, F): block q goes straight to device q
+        stacked = jax.device_put(np.stack(bufs), NamedSharding(mesh, P(axis)))
 
         def local(x):                                 # x: (1, parts, pad, F)
             return jax.lax.all_to_all(x, axis, split_axis=1, concat_axis=0)
 
-        recv = shard_map(local, mesh=mesh, in_specs=P(axis),
-                         out_specs=P(axis), check_rep=False)(stacked)
+        recv = jax.shard_map(local, mesh=mesh, in_specs=P(axis),
+                             out_specs=P(axis), check_vma=False)(stacked)
         # shard p's local (parts, 1, pad, F) blocks concatenate on axis 0
         recv = np.asarray(recv).reshape(plan.parts, plan.parts, pad,
                                         feat_dim)   # recv[p][q] = send[q][p]

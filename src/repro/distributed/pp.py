@@ -13,7 +13,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -65,9 +64,9 @@ def make_pipeline_fn(layer_fn: Callable, n_stages: int, n_micro: int,
             stage_axis)
         return last
 
-    return shard_map(pipelined, mesh=mesh,
-                     in_specs=(P(stage_axis), P()),
-                     out_specs=P(), check_rep=False)
+    return jax.shard_map(pipelined, mesh=mesh,
+                         in_specs=(P(stage_axis), P()),
+                         out_specs=P(), check_vma=False)
 
 
 def split_microbatches(x: jnp.ndarray, n_micro: int) -> jnp.ndarray:

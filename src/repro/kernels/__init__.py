@@ -1,7 +1,9 @@
 """Pallas TPU kernels for the compute hot-spots A³GNN optimizes.
 
 Each subpackage: ``kernel.py`` (pl.pallas_call + BlockSpec), ``ops.py``
-(jit'd wrapper with interpret/XLA fallbacks), ``ref.py`` (pure-jnp oracle).
+(jit'd wrapper: Pallas kernel or jnp oracle — ``platform.py`` compiles
+the kernel for a TPU and interprets it elsewhere), ``ref.py`` (pure-jnp
+oracle).
 
   reservoir/        vectorized weighted-reservoir top-m neighbor selection
   gather/           device-map feature-cache row gather

@@ -57,9 +57,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_q: int, block_k: int,
     o_ref[0] = (acc / jnp.maximum(l_i, 1e-30)[:, None]).astype(o_ref.dtype)
 
 
-def flash_attention_pallas(q, k, v, *, causal: bool = True,
-                           block_q: int = 128, block_k: int = 128,
-                           interpret: bool = True):
+def flash_attention_pallas(q, k, v, *, interpret: bool, causal: bool = True,
+                           block_q: int = 128, block_k: int = 128):
     """q/k/v (BH, S, Dh) → (BH, S, Dh).  S must divide by the blocks."""
     BH, S, Dh = q.shape
     block_q = min(block_q, S)

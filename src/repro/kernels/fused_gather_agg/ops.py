@@ -1,5 +1,5 @@
-"""jit wrapper: pad ids/dst rows to the id block (and F to the feature
-block) through the memoized pad plan, dispatch kernel/ref.
+"""jit wrapper: pad ids/dst rows to the id block through the memoized pad
+plan, dispatch kernel/ref.
 
 Contract (shared with ref.py, regression-tested in tests/test_fused_agg.py):
 ``enc (Ns,) int32`` encodes where each input id's feature row lives —
@@ -11,7 +11,9 @@ being the prefix of the input ids (``Nd ≤ Ns``).  Returns
 aggregate (``mode``: ``mean`` for GraphSAGE/GCN layer 0, ``sum`` for GIN)
 — without ever materializing the (Ns, F) batch tensor on the kernel
 path.  Padded dst rows are sliced away; padded enc entries resolve to
-``aux[0]`` and are never referenced by a real dst row.
+``aux[0]`` and are never referenced by a real dst row.  The Pallas path
+stages table and sideband whole in VMEM and ``enc`` in SMEM, and refuses
+them past their budgets (kernels/platform.py).
 """
 from __future__ import annotations
 
@@ -22,7 +24,8 @@ import jax.numpy as jnp
 
 from repro.kernels.fused_gather_agg.kernel import gather_aggregate_pallas
 from repro.kernels.fused_gather_agg.ref import gather_aggregate_ref
-from repro.kernels.pad_plan import feat_plan, pad_plan, row_plan
+from repro.kernels.pad_plan import pad_plan, row_plan
+from repro.kernels.platform import check_staged, pallas_on_platform
 
 
 def _id_plan(Nd: int, Ns: int):
@@ -33,26 +36,21 @@ def _id_plan(Nd: int, Ns: int):
     return pad_plan("fused_ids", (Nd, Ns), compute)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("mode", "use_pallas", "interpret"))
+@functools.partial(jax.jit, static_argnames=("mode", "use_pallas"))
 def gather_aggregate(enc, neigh_idx, cache, aux, mode: str = "mean",
-                     use_pallas: bool = True, interpret: bool = True):
+                     use_pallas: bool = True):
     Nd, fanout = neigh_idx.shape
     Ns = enc.shape[0]
-    C, F = cache.shape
     ndp, nsp = _id_plan(Nd, Ns)
     enc_p = jnp.pad(enc.astype(jnp.int32), (0, nsp - Ns),
                     constant_values=-1)
     idx_p = jnp.pad(neigh_idx.astype(jnp.int32), ((0, ndp - Nd), (0, 0)),
                     constant_values=-1)
     if use_pallas:
-        block_f, fp = feat_plan(F)
-        cache_p = cache if fp == F else jnp.pad(cache, ((0, 0), (0, fp - F)))
-        aux_p = aux if fp == F else jnp.pad(aux, ((0, 0), (0, fp - F)))
-        h, a = gather_aggregate_pallas(enc_p, idx_p, cache_p, aux_p,
-                                       mode=mode, block_f=block_f,
-                                       interpret=interpret)
-        h, a = h[:, :F], a[:, :F]
+        check_staged("gather_aggregate", vmem=[cache, aux], smem=[enc_p])
+        h, a = pallas_on_platform(
+            functools.partial(gather_aggregate_pallas, mode=mode),
+            enc_p, idx_p, cache, aux)
     else:
         h, a = gather_aggregate_ref(enc_p, idx_p, cache, aux, mode=mode)
     return h[:Nd].astype(cache.dtype), a[:Nd].astype(cache.dtype)

@@ -1,14 +1,17 @@
 """Pallas kernel: device-map feature-cache gather.
 
-Batch-generation hot loop on the device side: for each requested node id,
-look up its cache slot (device map, scalar-prefetched into SMEM) and copy
-the feature row from the HBM-resident cache into the output batch buffer.
-Misses (slot < 0) emit zero rows + a miss flag; the host fills them from the
-DRAM feature store (the paper's PCIe path, overlapped by pipeline mode 1/2).
+Batch-generation hot loop on the device side: for each requested node id
+the host passes its cache slot (scalar-prefetched into SMEM) and the
+kernel copies the feature row out of the cache table into the output
+batch buffer.  A miss (slot < 0) yields a zero row; the caller fills it
+from the host feature store (the paper's PCIe path, overlapped by
+pipeline mode 1/2) and already knows the miss set from the slots.
 
-Grid: (id_blocks, feature_blocks); ids are scalar-prefetched so the row DMA
-address is known before the block body runs (the Pallas analogue of the
-paper's "device map for efficient lookup").
+Grid: (id_blocks,).  The whole table is one block, staged into VMEM once
+per call (a block equal to the full array is single-buffered); the slots
+are scalar-prefetched so each row address is known before the block body
+runs (the Pallas analogue of the paper's "device map for efficient
+lookup").
 """
 from __future__ import annotations
 
@@ -19,47 +22,34 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
+from repro.kernels.platform import COMPILER_PARAMS
 
-def _gather_kernel(slots_ref, cache_ref, out_ref, miss_ref, *,
-                   ids_per_block: int, block_f: int):
-    fi = pl.program_id(1)
+
+def _gather_kernel(slots_ref, cache_ref, out_ref, *, ids_per_block: int):
     base = pl.program_id(0) * ids_per_block         # slots_ref is unblocked
     for r in range(ids_per_block):                  # static unroll (8 rows)
         slot = slots_ref[base + r]
-        hit = slot >= 0
-        safe = jnp.maximum(slot, 0)
-        row = pl.load(cache_ref, (pl.dslice(safe, 1), slice(None)))  # (1,Bf)
-        row = jnp.where(hit, row, jnp.zeros_like(row))
-        pl.store(out_ref, (pl.dslice(r, 1), slice(None)), row)
-        @pl.when(fi == 0)
-        def _():
-            miss_ref[r] = jnp.where(hit, 0, 1).astype(jnp.int32)
+        row = cache_ref[pl.ds(jnp.maximum(slot, 0), 1), :]          # (1, F)
+        out_ref[r:r + 1, :] = jnp.where(slot >= 0, row, jnp.zeros_like(row))
 
 
-def cache_gather_pallas(slots: jnp.ndarray, cache: jnp.ndarray,
-                        ids_per_block: int = 8, block_f: int = 512,
-                        interpret: bool = True):
-    """slots (n,) int32 (−1 = miss); cache (C, F) f32 →
-    (out (n, F) f32, miss (n,) int32)."""
+def cache_gather_pallas(slots: jnp.ndarray, cache: jnp.ndarray, *,
+                        interpret: bool, ids_per_block: int = 8):
+    """slots (n,) int32 (−1 = miss); cache (C, F) → out (n, F)."""
     n = slots.shape[0]
     C, F = cache.shape
-    block_f = min(block_f, F)
-    assert n % ids_per_block == 0 and F % block_f == 0
-    grid = (n // ids_per_block, F // block_f)
-    kernel = functools.partial(_gather_kernel, ids_per_block=ids_per_block,
-                               block_f=block_f)
+    assert n % ids_per_block == 0
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[pl.BlockSpec((C, block_f), lambda i, f, slots: (0, f))],
-        out_specs=[pl.BlockSpec((ids_per_block, block_f),
-                                lambda i, f, slots: (i, f)),
-                   pl.BlockSpec((ids_per_block,), lambda i, f, slots: (i,))],
+        grid=(n // ids_per_block,),
+        in_specs=[pl.BlockSpec((C, F), lambda i, slots: (0, 0))],
+        out_specs=pl.BlockSpec((ids_per_block, F), lambda i, slots: (i, 0)),
     )
     return pl.pallas_call(
-        kernel,
+        functools.partial(_gather_kernel, ids_per_block=ids_per_block),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((n, F), cache.dtype),
-                   jax.ShapeDtypeStruct((n,), jnp.int32)],
+        out_shape=jax.ShapeDtypeStruct((n, F), cache.dtype),
         interpret=interpret,
+        compiler_params=COMPILER_PARAMS,
+        name="cache_gather",
     )(slots, cache)

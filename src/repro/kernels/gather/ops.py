@@ -1,12 +1,12 @@
-"""jit wrapper: pad n to the id-block (and F to the feature block),
-dispatch kernel/ref.
+"""jit wrapper: pad n to the id block, dispatch kernel/ref.
 
 Contract (shared with ref.py, regression-tested in tests/test_kernels.py):
 ``slots (n,) int → (features (n, F) of ``cache.dtype``, miss (n,) int32)``
-for ANY n ≥ 1 and ANY feature width F — including widths that are not a
-multiple of the kernel's feature block (e.g. the reddit twin's F=602).
-Padded id rows are synthesized as misses and sliced away; padded feature
-columns are zero and sliced away.
+for ANY n ≥ 1 and ANY feature width F (e.g. the reddit twin's F=602).
+Miss rows are zero; ``miss`` is derived from ``slots < 0`` on both paths.
+Padded id rows are synthesized as misses and sliced away.  The Pallas
+path stages the whole table in VMEM and the slots in SMEM, and refuses
+either past its budget (kernels/platform.py).
 """
 from __future__ import annotations
 
@@ -17,29 +17,24 @@ import jax.numpy as jnp
 
 from repro.kernels.gather.kernel import cache_gather_pallas
 from repro.kernels.gather.ref import cache_gather_ref
+from repro.kernels.pad_plan import row_plan
+from repro.kernels.platform import check_staged, pallas_on_platform
 
 
-@functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
-def cache_gather(slots, cache, use_pallas: bool = True,
-                 interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("use_pallas",))
+def cache_gather(slots, cache, use_pallas: bool = True):
     """slots (n,) int32 (−1 miss) → (features (n,F), miss (n,) int32)."""
     n = slots.shape[0]
-    C, F = cache.shape
-    np_ = -(-n // 8) * 8
-    slots_p = jnp.pad(slots.astype(jnp.int32), (0, np_ - n),
-                      constant_values=-1)
+    slots = slots.astype(jnp.int32)
     if use_pallas:
-        # feature blocking: full-width when one block suffices, else a
-        # lane-aligned block size that divides the (padded) width
-        if F <= 512:
-            block_f, fp = F, F
-        else:
-            block_f = 512 if F % 512 == 0 else 128
-            fp = -(-F // block_f) * block_f
-        cache_p = cache if fp == F else jnp.pad(cache, ((0, 0), (0, fp - F)))
-        out, miss = cache_gather_pallas(slots_p, cache_p, block_f=block_f,
-                                        interpret=interpret)
-        out = out[:, :F]
+        # the kernel moves single 32-bit rows (Mosaic cannot slice one row
+        # of a packed 16-bit tile at a dynamic offset): a narrower table is
+        # widened per call, exactly — every production table is float32
+        table = cache if cache.dtype.itemsize == 4 else cache.astype(
+            jnp.float32)
+        slots_p = jnp.pad(slots, (0, row_plan(n) - n), constant_values=-1)
+        check_staged("cache_gather", vmem=[table], smem=[slots_p])
+        out = pallas_on_platform(cache_gather_pallas, slots_p, table)[:n]
     else:
-        out, miss = cache_gather_ref(slots_p, cache)
-    return out[:n].astype(cache.dtype), miss[:n].astype(jnp.int32)
+        out = cache_gather_ref(slots, cache)
+    return out.astype(cache.dtype), (slots < 0).astype(jnp.int32)

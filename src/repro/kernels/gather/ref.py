@@ -5,7 +5,5 @@ import jax.numpy as jnp
 
 
 def cache_gather_ref(slots, cache):
-    hit = slots >= 0
     rows = cache[jnp.maximum(slots, 0)]
-    out = jnp.where(hit[:, None], rows, 0.0)
-    return out, (~hit).astype(jnp.int32)
+    return jnp.where((slots >= 0)[:, None], rows, 0.0)

@@ -43,9 +43,8 @@ def _topm_kernel(w_ref, u_ref, mask_ref, idx_ref, key_ref, *, m: int):
 
 
 def reservoir_topm_pallas(weights: jnp.ndarray, u: jnp.ndarray,
-                          mask: jnp.ndarray, m: int,
-                          block_rows: int = 8,
-                          interpret: bool = True):
+                          mask: jnp.ndarray, m: int, *, interpret: bool,
+                          block_rows: int = 8):
     """weights/u (R, Npad) f32, mask (R, Npad) int32 → (idx (R,m) i32,
     keys (R,m) f32).  idx = Npad marks an exhausted row (fewer than m valid)."""
     R, npad = weights.shape

@@ -6,6 +6,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.platform import pallas_on_platform
 from repro.kernels.reservoir.kernel import reservoir_topm_pallas
 from repro.kernels.reservoir.ref import reservoir_topm_ref
 
@@ -15,9 +16,8 @@ def _pad_to(x, rows, cols, value):
     return jnp.pad(x, ((0, rows - R), (0, cols - C)), constant_values=value)
 
 
-@functools.partial(jax.jit, static_argnames=("m", "use_pallas", "interpret"))
-def reservoir_topm(weights, u, mask, m: int, use_pallas: bool = True,
-                   interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("m", "use_pallas"))
+def reservoir_topm(weights, u, mask, m: int, use_pallas: bool = True):
     """Top-m ES selection over padded neighbor rows.
 
     weights (R,N) f32; u (R,N) uniforms; mask (R,N) bool/int.
@@ -28,8 +28,10 @@ def reservoir_topm(weights, u, mask, m: int, use_pallas: bool = True,
     wp = _pad_to(weights.astype(jnp.float32), Rp, Np, 1.0)
     up = _pad_to(u.astype(jnp.float32), Rp, Np, 0.0)
     mp = _pad_to(mask.astype(jnp.int32), Rp, Np, 0)
-    fn = (functools.partial(reservoir_topm_pallas, interpret=interpret)
-          if use_pallas else reservoir_topm_ref)
-    idx, keys = fn(wp, up, mp, m)
+    if use_pallas:
+        idx, keys = pallas_on_platform(
+            functools.partial(reservoir_topm_pallas, m=m), wp, up, mp)
+    else:
+        idx, keys = reservoir_topm_ref(wp, up, mp, m)
     idx = jnp.where(idx >= Np, N, idx)     # normalize exhausted marker
     return idx[:R], keys[:R]

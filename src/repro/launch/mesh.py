@@ -12,18 +12,25 @@ from dataclasses import dataclass
 import numpy as np
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with ``Auto`` axes: the sharding rules
+    (distributed/sharding.py) place arrays with sharding constraints,
+    which only ``Auto`` axes accept."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Single-device mesh for CPU smoke tests (axis names preserved)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))
 
 
 @dataclass(frozen=True)
@@ -60,10 +67,24 @@ def make_partition_mesh(num_partitions: int, axis: str = "part"):
     return HostSimMesh(num_partitions, axis)
 
 
-# TPU v5e hardware constants (per chip) — used by the roofline analysis.
-HW = {
-    "peak_flops_bf16": 197e12,   # FLOP/s
-    "hbm_bw": 819e9,             # B/s
-    "ici_bw": 50e9,              # B/s per link
-    "hbm_bytes": 16 * 1024**3,   # 16 GiB
+# Per-chip peaks, keyed by ``jax.Device.device_kind``.  TPU v5e: Google
+# Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM at 819 GB/s,
+# 1,600 Gbit/s of inter-chip interconnect per chip; ici_bw is one of its
+# four links).
+PEAKS = {
+    "TPU v5 lite": {
+        "peak_flops_bf16": 197e12,   # FLOP/s
+        "hbm_bw": 819e9,             # B/s
+        "ici_bw": 50e9,              # B/s per link
+        "hbm_bytes": 16 * 10**9,     # 16 GB
+    },
 }
+
+
+def peaks(device_kind: str) -> dict:
+    """The peak table of ``device_kind``; an unknown kind is an error."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no peak table for device kind {device_kind!r} "
+                         f"(known: {sorted(PEAKS)})") from None

@@ -235,6 +235,8 @@ def main():
                          "another-replica (≤ 0 disables — the pre-seam "
                          "behavior; fabric only)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.gnn or args.arch.startswith("graphsage"):
         return run_gnn_serve(args)

@@ -9,8 +9,7 @@ Two workloads behind one CLI:
   * LM (assigned archs): --arch minitron-8b --smoke ... runs the reduced
     config on the host mesh with the real train step, host data pipeline,
     checkpointing and fault-tolerance supervisor.  On a real TPU slice the
-    same code path takes the production mesh (launch/mesh.py) — XLA flags
-    for latency-hiding collectives are set below.
+    same code path takes the production mesh (launch/mesh.py).
 
 Examples:
   PYTHONPATH=src python -m repro.launch.train --arch graphsage-products --steps 30
@@ -21,17 +20,7 @@ Examples:
 from __future__ import annotations
 
 import argparse
-import os
 import time
-
-
-def _tpu_xla_flags():
-    """Latency-hiding scheduler + async collectives for real TPU runs."""
-    flags = os.environ.get("LIBTPU_INIT_ARGS", "")
-    os.environ["LIBTPU_INIT_ARGS"] = flags + (
-        " --xla_tpu_enable_latency_hiding_scheduler=true"
-        " --xla_tpu_enable_async_collective_fusion=true"
-        " --xla_enable_async_all_gather=true")
 
 
 def run_gnn_multipartition(args, cfg, graph):
@@ -236,6 +225,8 @@ def main():
     ap.add_argument("--workers", type=int, default=2)
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.arch.startswith("graphsage"):
         return run_gnn(args)

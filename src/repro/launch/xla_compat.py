@@ -8,15 +8,7 @@ from __future__ import annotations
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """Normalized ``compiled.cost_analysis()``.
-
-    jax ≤0.4.x returns a one-element list of per-program dicts; newer
-    releases return the dict directly (and may return None when the backend
-    provides no analysis).  Downstream cost code always wants a flat dict.
-    """
+    """``compiled.cost_analysis()`` as a dict (``{}`` when the backend
+    provides no analysis)."""
     ca = compiled.cost_analysis()
-    if ca is None:
-        return {}
-    if isinstance(ca, (list, tuple)):
-        return dict(ca[0]) if len(ca) else {}
-    return dict(ca)
+    return dict(ca) if ca else {}

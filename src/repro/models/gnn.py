@@ -20,8 +20,8 @@ Every layer has two expressions of the same math:
   (pad_src0, F) input-feature tensor never materializes either.
 
 The train steps always run the fused kernels with ``use_pallas=False``:
-the jitted pure-jnp oracle is the production path on CPU hosts and is
-differentiable (the Pallas path is forward-only today).
+the jitted pure-jnp oracle is differentiable, and the Pallas path is
+forward-only today.
 """
 from __future__ import annotations
 
@@ -118,40 +118,40 @@ _COMBINE = {"graphsage": (_sage_combine, "mean"),
             "gin": (_gin_combine, "sum")}
 
 
-def _agg(h_src, neigh_idx, mode, *, fused, use_pallas, interpret):
+def _agg(h_src, neigh_idx, mode, *, fused, use_pallas):
     if fused:
         return neighbor_agg(neigh_idx, h_src, mode=mode,
-                            use_pallas=use_pallas, interpret=interpret)
+                            use_pallas=use_pallas)
     return _mean_agg(h_src, neigh_idx) if mode == "mean" \
         else _sum_agg(h_src, neigh_idx)
 
 
 def sage_layer(p, h_src, neigh_idx, *, act=True, fused=False,
-               use_pallas=False, interpret=False):
+               use_pallas=False):
     h_dst = h_src[:neigh_idx.shape[0]]
     agg = _agg(h_src, neigh_idx, "mean", fused=fused,
-               use_pallas=use_pallas, interpret=interpret)
+               use_pallas=use_pallas)
     return _sage_combine(p, h_dst, agg, neigh_idx, act)
 
 
 def gcn_layer(p, h_src, neigh_idx, *, act=True, fused=False,
-              use_pallas=False, interpret=False):
+              use_pallas=False):
     h_dst = h_src[:neigh_idx.shape[0]]
     agg = _agg(h_src, neigh_idx, "mean", fused=fused,
-               use_pallas=use_pallas, interpret=interpret)
+               use_pallas=use_pallas)
     return _gcn_combine(p, h_dst, agg, neigh_idx, act)
 
 
 def gin_layer(p, h_src, neigh_idx, *, act=True, fused=False,
-              use_pallas=False, interpret=False):
+              use_pallas=False):
     h_dst = h_src[:neigh_idx.shape[0]]
     agg = _agg(h_src, neigh_idx, "sum", fused=fused,
-               use_pallas=use_pallas, interpret=interpret)
+               use_pallas=use_pallas)
     return _gin_combine(p, h_dst, agg, neigh_idx, act)
 
 
 def gat_layer(p, h_src, neigh_idx, *, act=True, fused=False,
-              use_pallas=False, interpret=False):
+              use_pallas=False):
     n_dst = neigh_idx.shape[0]
     z_src = h_src @ p["w"]                               # (Ns,D')
     z_dst = z_src[:n_dst]
@@ -168,8 +168,8 @@ def gat_layer(p, h_src, neigh_idx, *, act=True, fused=False,
         e_self = jax.nn.leaky_relu(z_dst @ (p["a_src"] + p["a_dst"]))[:, None]
         alla = jax.nn.softmax(jnp.concatenate([e, e_self], axis=1), axis=1)
         agg = neighbor_agg(neigh_idx, z_src, mode="sum",
-                           weights=alla[:, :-1], use_pallas=use_pallas,
-                           interpret=interpret) + alla[:, -1:] * z_dst
+                           weights=alla[:, :-1],
+                           use_pallas=use_pallas) + alla[:, -1:] * z_dst
     else:
         nb, mask = _gather_neighbors(z_src, neigh_idx)   # (Nd,F,D')
         e = jax.nn.leaky_relu(nb @ p["a_src"] + (z_dst @ p["a_dst"])[:, None],
@@ -188,7 +188,7 @@ _LAYER_FNS = {"graphsage": sage_layer, "gcn": gcn_layer, "gat": gat_layer,
 
 
 def gnn_forward(params, features, neigh_idxs: List[jnp.ndarray], cfg, *,
-                fused=False, use_pallas=False, interpret=False):
+                fused=False, use_pallas=False):
     """features (pad_src0, F); neigh_idxs[i] (pad_dst_i, fanout_i) with the
     chained-padding invariant pad_dst_i == pad_src_{i+1}."""
     fn = _LAYER_FNS[cfg.model]
@@ -196,12 +196,12 @@ def gnn_forward(params, features, neigh_idxs: List[jnp.ndarray], cfg, *,
     n = len(params["layers"])
     for i, (p, idx) in enumerate(zip(params["layers"], neigh_idxs)):
         h = fn(p, h, idx, act=(i < n - 1), fused=fused,
-               use_pallas=use_pallas, interpret=interpret)
+               use_pallas=use_pallas)
     return h                                              # (pad_seeds, classes)
 
 
 def gnn_forward_allfused(params, enc0, aux0, table, neigh_idxs, cfg, *,
-                         use_pallas=False, interpret=False):
+                         use_pallas=False):
     """All-hop fused forward: layer-0 inputs are the encoded slots ``enc0``
     resolved against the feature-plane cache ``table`` and the host miss
     sideband ``aux0`` (kernels/fused_gather_agg) — the (pad_src0, F)
@@ -210,7 +210,7 @@ def gnn_forward_allfused(params, enc0, aux0, table, neigh_idxs, cfg, *,
     dt = jnp.dtype(cfg.compute_dtype)
     n = len(params["layers"])
     p0, idx0 = params["layers"][0], neigh_idxs[0]
-    kw = dict(fused=True, use_pallas=use_pallas, interpret=interpret)
+    kw = dict(fused=True, use_pallas=use_pallas)
     if cfg.model == "gat":
         # attention needs the per-src projection: resolve the rows (still no
         # neighbor tensor) and run the fused GAT layer on them
@@ -219,8 +219,7 @@ def gnn_forward_allfused(params, enc0, aux0, table, neigh_idxs, cfg, *,
     else:
         combine, mode = _COMBINE[cfg.model]
         h_dst, agg = gather_aggregate(enc0, idx0, table, aux0, mode=mode,
-                                      use_pallas=use_pallas,
-                                      interpret=interpret)
+                                      use_pallas=use_pallas)
         h = combine(p0, h_dst.astype(dt), agg.astype(dt), idx0, act=(n > 1))
     fn = _LAYER_FNS[cfg.model]
     for i, (p, idx) in enumerate(zip(params["layers"][1:], neigh_idxs[1:]),

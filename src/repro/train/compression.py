@@ -105,19 +105,18 @@ def make_crosspod_grad_transform(mesh, kind: str = "int8"):
     """grad_transform hook for make_train_step: reduce grads over the pod
     axis with compression (shard_map over 'pod'; other axes untouched)."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     if "pod" not in mesh.axis_names:
         return None
 
     def transform(grads):
         def red(g):
-            fn = shard_map(
+            fn = jax.shard_map(
                 lambda t: compressed_psum_int8(t, "pod"),
                 mesh=mesh,
                 in_specs=P(*((None,) * g.ndim)),
                 out_specs=P(*((None,) * g.ndim)),
-                check_rep=False)
+                check_vma=False)
             return fn(g)
         return jax.tree.map(red, grads)
 

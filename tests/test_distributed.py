@@ -162,15 +162,14 @@ def test_compressed_psum_shardmap():
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
         from functools import partial
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.train.compression import compressed_psum_int8
         mesh = jax.make_mesh((8,), ("pod",))
         x = jnp.asarray(np.random.default_rng(0).normal(0, 1, (8, 128)),
                         jnp.float32)
-        fn = shard_map(lambda t: compressed_psum_int8(t, "pod"), mesh=mesh,
-                       in_specs=P("pod", None), out_specs=P("pod", None),
-                       check_rep=False)
+        fn = jax.shard_map(lambda t: compressed_psum_int8(t, "pod"),
+                           mesh=mesh, in_specs=P("pod", None),
+                           out_specs=P("pod", None), check_vma=False)
         out = jax.jit(fn)(x)
         want = jnp.mean(x, axis=0)      # mean over the pod axis
         got = np.asarray(out)[0]
@@ -224,3 +223,21 @@ def test_dryrun_cell_tiny_mesh():
         print("CELL_OK")
     """, devices=512, timeout=900)
     assert "CELL_OK" in out
+
+
+def test_peaks_keyed_by_device_kind():
+    """The roofline peaks are looked up by ``device_kind``; a device kind
+    with no table is an error, never the v5e numbers by default."""
+    from repro.launch.mesh import peaks
+    assert peaks("TPU v5 lite")["hbm_bw"] == 819e9
+    with pytest.raises(ValueError, match="no peak table"):
+        peaks("cpu")
+
+
+def test_production_mesh_axes_are_auto():
+    """Sharding constraints (distributed/sharding.py) need Auto axes;
+    ``jax.make_mesh`` now defaults to Explicit ones."""
+    from jax.sharding import AxisType
+    from repro.launch.mesh import make_host_mesh
+    mesh = make_host_mesh()
+    assert all(t == AxisType.Auto for t in mesh.axis_types)

@@ -1,4 +1,6 @@
-"""Per-kernel shape/dtype sweeps vs pure-jnp oracles (interpret mode)."""
+"""Per-kernel shape/dtype sweeps vs pure-jnp oracles (on CPU the Pallas
+kernels run interpreted; tests/test_tpu_compile.py compiles them for a
+TPU)."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -136,27 +138,30 @@ def test_segment_agg_all_padded_row():
 
 
 def test_segment_agg_forwards_interpret_flag(monkeypatch):
-    """Regression: the ops wrapper declared ``interpret`` as a static jit
-    arg but never forwarded it to the Pallas entry point (which defaults
-    to interpret=True) — on a real TPU/GPU the aggregation kernel would
-    silently run interpreted.  Spy on the kernel entry point and assert it
-    sees the caller's value for both settings."""
+    """Regression: the aggregation kernel once ran interpreted on a real
+    TPU because the wrapper dropped the caller's flag.  The wrapper now
+    decides it from the platform it is lowered for: it hands the kernel
+    interpret=False for a TPU and interpret=True for any other backend,
+    and only the branch for the lowering platform is lowered.  Spy on
+    the kernel entry point and assert both branches are built."""
+    import jax
     from repro.kernels.segment_agg import ops as agg_ops
     from repro.kernels.segment_agg.kernel import neighbor_agg_pallas
     seen = []
 
-    def spy(idx, h, *args, interpret=True, **kw):
+    def spy(idx, h, *args, interpret, **kw):
         seen.append(interpret)
-        # execute interpreted regardless — compiled Pallas is not
-        # available on a CPU test host
-        return neighbor_agg_pallas(idx, h, *args, interpret=True, **kw)
+        return neighbor_agg_pallas(idx, h, *args, interpret=interpret, **kw)
 
     monkeypatch.setattr(agg_ops, "neighbor_agg_pallas", spy)
     h = jnp.ones((11, 128), jnp.float32)          # distinctive shape: the
     idx = jnp.zeros((3, 2), jnp.int32)            # jit cache must retrace
-    for flag in (True, False):
-        agg_ops.neighbor_mean(idx, h, use_pallas=True, interpret=flag)
-    assert seen == [True, False]
+    lowered = agg_ops.neighbor_mean.lower(idx, h, use_pallas=True)
+    assert sorted(seen) == [False, True]
+    on_tpu = jax.default_backend() == "tpu"
+    assert ("tpu_custom_call" in lowered.as_text()) == on_tpu
+    out = agg_ops.neighbor_mean(idx, h, use_pallas=True)
+    np.testing.assert_allclose(np.asarray(out), np.ones((3, 128)))
 
 
 # ---------------------------------------------------------------------------

@@ -25,13 +25,19 @@ def test_all_modes_complete_and_learn(smoke_graph, smoke_gnn_cfg):
         assert res.stats.losses[-1] < res.stats.losses[0]
 
 
-def test_worker_failure_reissued(smoke_graph, smoke_gnn_cfg):
-    """A dying sampler worker must not lose work items (node-failure path)."""
+def test_worker_failure_reissued(smoke_graph, smoke_gnn_cfg, caplog):
+    """A dying sampler worker must not lose work items (node-failure path),
+    and every failure is logged with its exception, not swallowed."""
     cfg = smoke_gnn_cfg.replace(parallel_mode="mode1", workers=2)
     tr = A3GNNTrainer(smoke_graph, cfg, seed=0)
-    res = tr.run_epochs(1, max_steps_per_epoch=10, fail_worker=0)
+    with caplog.at_level("WARNING", logger="repro.core.pipeline"):
+        res = tr.run_epochs(1, max_steps_per_epoch=10, fail_worker=0)
     assert res.stats.steps == 10            # all steps completed
     assert res.stats.reissued >= 1          # failed items re-issued
+    failures = [r for r in caplog.records if "re-issuing" in r.getMessage()]
+    assert len(failures) == res.stats.reissued
+    assert all(r.exc_info and "injected failure" in str(r.exc_info[1])
+               for r in failures)
 
 
 def test_memory_model_ordering():
