@@ -28,6 +28,11 @@ import time
 from pathlib import Path
 
 
+STEPS = 5          # training steps (global steps with --chips 4)
+QUERIES = 16       # node queries served
+BATCH = 4          # serving engine slots
+
+
 class CheckFailed(Exception):
     pass
 
@@ -91,7 +96,7 @@ def train_and_serve(args) -> None:
 
     try:
         losses = []
-        for step in range(args.steps):
+        for step in range(STEPS):
             t0 = time.perf_counter()
             stats = pipe.run(max_steps=1)
             losses += stats.losses
@@ -102,8 +107,8 @@ def train_and_serve(args) -> None:
                   flush=True)
     finally:
         pipe.shutdown()               # workers down; the plane stays live
-    check(len(losses) == args.steps and bool(np.isfinite(losses).all()),
-          f"{args.steps} finite losses")
+    check(len(losses) == STEPS and bool(np.isfinite(losses).all()),
+          f"{STEPS} finite losses")
 
     # rows the device plane fetches == the host feature store, for cache
     # hits on the trainer's plane and for hits and misses on a plane whose
@@ -122,11 +127,11 @@ def train_and_serve(args) -> None:
               f"{name} fetch equals graph.features exactly")
 
     # serving on the trainer's plane vs. the host plane, same params
-    nodes = rng.choice(np.where(graph.test_mask)[0], size=args.queries,
+    nodes = rng.choice(np.where(graph.test_mask)[0], size=QUERIES,
                        replace=False)
     preds = {}
     for name, p in (("device", plane), ("host", HostFeaturePlane(graph))):
-        eng = GNNInferenceEngine.from_trainer(tr, batch=args.batch, plane=p,
+        eng = GNNInferenceEngine.from_trainer(tr, batch=BATCH, plane=p,
                                               seed=args.seed)
         for rid, v in enumerate(nodes):
             eng.submit(GNNRequest(rid=rid, node=int(v)))
@@ -136,8 +141,8 @@ def train_and_serve(args) -> None:
         print(f"[serve] {name} plane: {st['completed']} queries in "
               f"{st['engine_steps']} engine steps "
               f"({time.perf_counter() - t0:.1f} s incl. compile)", flush=True)
-    check(len(preds["device"]) == args.queries,
-          f"{args.queries} queries served")
+    check(len(preds["device"]) == QUERIES,
+          f"{QUERIES} queries served")
     check(preds["device"] == preds["host"],
           "device-plane predictions equal host-plane predictions")
 
@@ -177,7 +182,7 @@ def multipartition(args) -> None:
 
     tr._allreduce = spy
     try:
-        for step in range(args.steps):
+        for step in range(STEPS):
             t0 = time.perf_counter()
             tr.global_step()
             losses = [s.pipe.stats.losses[-1] for s in tr.slots]
@@ -221,9 +226,6 @@ def main(argv=None) -> int:
                     help="4 runs only the multi-partition phase")
     ap.add_argument("--smoke", action="store_true",
                     help="small config (for CPU rehearsals)")
-    ap.add_argument("--steps", type=int, default=5)
-    ap.add_argument("--queries", type=int, default=16)
-    ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
     try:

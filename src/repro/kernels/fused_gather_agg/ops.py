@@ -25,7 +25,8 @@ import jax.numpy as jnp
 from repro.kernels.fused_gather_agg.kernel import gather_aggregate_pallas
 from repro.kernels.fused_gather_agg.ref import gather_aggregate_ref
 from repro.kernels.pad_plan import pad_plan, row_plan
-from repro.kernels.platform import check_staged, pallas_on_platform
+from repro.kernels.platform import (PLANE_REMEDY, check_staged,
+                                    pallas_on_platform)
 
 
 def _id_plan(Nd: int, Ns: int):
@@ -47,7 +48,8 @@ def gather_aggregate(enc, neigh_idx, cache, aux, mode: str = "mean",
     idx_p = jnp.pad(neigh_idx.astype(jnp.int32), ((0, ndp - Nd), (0, 0)),
                     constant_values=-1)
     if use_pallas:
-        check_staged("gather_aggregate", vmem=[cache, aux], smem=[enc_p])
+        check_staged("gather_aggregate", vmem=[cache, aux], smem=[enc_p],
+                     remedy=PLANE_REMEDY + ", or a smaller batch or fanout")
         h, a = pallas_on_platform(
             functools.partial(gather_aggregate_pallas, mode=mode),
             enc_p, idx_p, cache, aux)

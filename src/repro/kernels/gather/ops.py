@@ -18,7 +18,8 @@ import jax.numpy as jnp
 from repro.kernels.gather.kernel import cache_gather_pallas
 from repro.kernels.gather.ref import cache_gather_ref
 from repro.kernels.pad_plan import row_plan
-from repro.kernels.platform import check_staged, pallas_on_platform
+from repro.kernels.platform import (PLANE_REMEDY, check_staged,
+                                    pallas_on_platform)
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas",))
@@ -33,7 +34,8 @@ def cache_gather(slots, cache, use_pallas: bool = True):
         table = cache if cache.dtype.itemsize == 4 else cache.astype(
             jnp.float32)
         slots_p = jnp.pad(slots, (0, row_plan(n) - n), constant_values=-1)
-        check_staged("cache_gather", vmem=[table], smem=[slots_p])
+        check_staged("cache_gather", vmem=[table], smem=[slots_p],
+                     remedy=PLANE_REMEDY)
         out = pallas_on_platform(cache_gather_pallas, slots_p, table)[:n]
     else:
         out = cache_gather_ref(slots, cache)

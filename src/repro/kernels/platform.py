@@ -8,7 +8,7 @@ backend the same call runs the Pallas interpreter, which is how the
 kernels are tested on CPU hosts.  No caller passes an ``interpret`` flag.
 
 The GNN kernels stage whole tables (the feature cache, a layer's source
-buffer) as one VMEM block.  ``check_vmem_staged`` refuses, with a clear
+buffer) as one VMEM block.  ``check_staged`` refuses, with a clear
 error and on every platform, a shape the TPU compiler would refuse.
 """
 from __future__ import annotations
@@ -28,6 +28,9 @@ VMEM_LIMIT_BYTES = 120 * 2**20
 VMEM_STAGED_BYTES = 100 * 2**20
 SMEM_PREFETCH_BYTES = 900 * 2**10
 COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
+# what a feature plane's user changes when its cache table is over budget
+PLANE_REMEDY = ("set a smaller cache volume (GNNConfig.cache_volume_mb, "
+                "Θ), or sampling_device='cpu' to read features on the host")
 
 
 def pallas_on_platform(kernel, *args):
@@ -53,21 +56,19 @@ def _shapes(arrays) -> str:
                       for a in arrays)
 
 
-def check_staged(kernel: str, vmem=(), smem=()) -> None:
+def check_staged(kernel: str, vmem=(), smem=(), *, remedy: str) -> None:
     """Raise if the whole-array VMEM blocks ``vmem`` or the
-    scalar-prefetched ``smem`` arrays exceed the kernel budgets."""
+    scalar-prefetched ``smem`` arrays exceed the kernel budgets;
+    ``remedy`` tells the caller what to change."""
     need = sum(vmem_bytes(a.shape, a.dtype) for a in vmem)
     if need > VMEM_STAGED_BYTES:
         raise ValueError(
             f"{kernel}: the Pallas kernel stages {_shapes(vmem)} whole in "
             f"VMEM, {need / 2**20:.1f} MiB padded, over its "
-            f"{VMEM_STAGED_BYTES / 2**20:.0f} MiB budget; use a smaller "
-            f"table (e.g. a smaller cache volume) or the jnp path "
-            f"(use_pallas=False)")
+            f"{VMEM_STAGED_BYTES / 2**20:.0f} MiB budget; {remedy}")
     need = sum(a.size * np.dtype(a.dtype).itemsize for a in smem)
     if need > SMEM_PREFETCH_BYTES:
         raise ValueError(
             f"{kernel}: the Pallas kernel prefetches {_shapes(smem)} into "
             f"SMEM, {need / 2**10:.0f} KiB, over its "
-            f"{SMEM_PREFETCH_BYTES / 2**10:.0f} KiB budget; split the call "
-            f"or use the jnp path (use_pallas=False)")
+            f"{SMEM_PREFETCH_BYTES / 2**10:.0f} KiB budget; {remedy}")

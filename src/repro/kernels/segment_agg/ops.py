@@ -38,7 +38,8 @@ def neighbor_agg(neigh_idx, h_src, mode: str = "mean", weights=None,
     w_p = (None if weights is None
            else jnp.pad(weights, ((0, ndp - Nd), (0, 0))))
     if use_pallas:
-        check_staged("neighbor_agg", vmem=[h_src])
+        check_staged("neighbor_agg", vmem=[h_src],
+                     remedy="pass fewer source rows, or use_pallas=False")
 
         def kernel(idx, h, w, *, interpret):
             return neighbor_agg_pallas(idx, h, mode=mode, weights=w,

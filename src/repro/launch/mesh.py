@@ -76,7 +76,7 @@ PEAKS = {
         "peak_flops_bf16": 197e12,   # FLOP/s
         "hbm_bw": 819e9,             # B/s
         "ici_bw": 50e9,              # B/s per link
-        "hbm_bytes": 16 * 10**9,     # 16 GB
+        "hbm_bytes": 16 * 1024**3,   # 16 GiB
     },
 }
 

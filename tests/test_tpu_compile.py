@@ -27,11 +27,9 @@ CHUNK = 4096                   # core/feature_plane.py GATHER_CHUNK_ROWS
 def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
-    try:
-        return topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 — no TPU compiler installed
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # no fallback: without the TPU compiler these tests must error, not skip
+    return topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
 
 
 @pytest.fixture(scope="module")
@@ -103,7 +101,7 @@ def test_gather_aggregate_compiles(one_chip, Nd, fanout, Ns):
 def test_over_budget_shapes_raise_clear_errors(one_chip):
     """Past the staging budgets the wrappers refuse before compiling: a
     cache table too large for VMEM, an encoding too large for SMEM."""
-    with pytest.raises(ValueError, match="VMEM"):
+    with pytest.raises(ValueError, match="VMEM.*cache_volume_mb"):
         jax.jit(lambda s, c: cache_gather(s, c)).lower(
             _spec(one_chip, (CHUNK,), jnp.int32),
             _spec(one_chip, (210_000, 100)))
