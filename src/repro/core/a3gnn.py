@@ -25,6 +25,7 @@ from repro.core.locality import bias_weight_fn, accuracy_drop_model
 from repro.core.pipeline import Pipeline, PipelineStats
 from repro.core.perf_model import MemoryTerms, memory_seq, memory_mode1, memory_mode2
 from repro.core.sampling import NeighborSampler, seed_loader
+from repro.core.spans import host_bytes, level_rows, span
 from repro.graph.batch import (generate_batch, batch_device_arrays,
                                compute_level_caps)
 from repro.graph.partition import partition, overlap_ratio
@@ -115,26 +116,34 @@ class A3GNNTrainer(TrainerCheckpointMixin, FeatureStreamConsumer):
 
     # ------------------------------------------------------------------
     def _train_fn(self, mb, plane=None):
-        if (self._step_allfused is not None and plane is not None
-                and mb.features is None and mb.blocks):
-            # all-hop fused path: level-capped buffers → one jit
-            # signature per (model, level_caps); the input hop is
-            # resolved at step time through the plane (encoded slots +
-            # miss sideband — no feature tensor ever rides the batch)
-            caps = compute_level_caps(len(mb.seeds), self.cfg.fanout,
-                                      self.graph.num_nodes)
-            arrays = batch_device_arrays(mb, level_caps=caps)
-            enc0, aux0, table = plane.fused_inputs(mb.input_ids,
-                                                   arrays["pads"][0])
-            self.params, self.opt_state, loss, acc = self._step_allfused(
-                self.params, self.opt_state, enc0, aux0, table,
-                arrays["neigh_idxs"], arrays["labels"])
-        else:
-            arrays = batch_device_arrays(mb)
-            self.params, self.opt_state, loss, acc = self._step(
-                self.params, self.opt_state, arrays["features"],
-                arrays["neigh_idxs"], arrays["labels"])
-        return float(loss), float(acc)
+        # train.feed: padding, the copy of the host arrays to the chip and
+        # the dispatch; train.sync: the wait for the step's loss
+        with span("train.feed") as feed:
+            if (self._step_allfused is not None and plane is not None
+                    and mb.features is None and mb.blocks):
+                # all-hop fused path: level-capped buffers → one jit
+                # signature per (model, level_caps); the input hop is
+                # resolved at step time through the plane (encoded slots
+                # + miss sideband — no feature tensor rides the batch)
+                caps = compute_level_caps(len(mb.seeds), self.cfg.fanout,
+                                          self.graph.num_nodes)
+                arrays = batch_device_arrays(mb, level_caps=caps)
+                enc0, aux0, table = plane.fused_inputs(mb.input_ids,
+                                                       arrays["pads"][0])
+                inputs = (enc0, aux0, table, arrays["neigh_idxs"],
+                          arrays["labels"])
+                step = self._step_allfused
+            else:
+                arrays = batch_device_arrays(mb)
+                inputs = (arrays["features"], arrays["neigh_idxs"],
+                          arrays["labels"])
+                step = self._step
+            feed.set_metadata(h2d_bytes=host_bytes(inputs),
+                              **level_rows(arrays))
+            self.params, self.opt_state, loss, acc = step(
+                self.params, self.opt_state, *inputs)
+        with span("train.sync"):
+            return float(loss), float(acc)
 
     # ------------------------------------------------------------------
     def run_epochs(self, epochs: int = 1, max_steps_per_epoch: Optional[int] = None,

@@ -38,11 +38,13 @@ from __future__ import annotations
 
 import logging
 import threading
+from contextlib import contextmanager
 from typing import Optional
 
 import numpy as np
 
 from repro.core.cache import FeatureCache
+from repro.core.spans import span
 from repro.graph.storage import Graph
 from repro.kernels.pad_plan import bucket_plan
 
@@ -356,6 +358,17 @@ class DeviceFeaturePlane(FeaturePlane):
         # interleavings), so sync + gather + insert run under one lock
         self._lock = threading.Lock()
 
+    @contextmanager
+    def _locked(self):
+        """Hold the plane's lock; the wait for it is the
+        ``plane.lock_wait`` span."""
+        with span("plane.lock_wait"):
+            self._lock.acquire()
+        try:
+            yield
+        finally:
+            self._lock.release()
+
     # -- device mirror -------------------------------------------------------
     def _ensure_synced(self):
         c = self.cache
@@ -424,7 +437,7 @@ class DeviceFeaturePlane(FeaturePlane):
         if c is None or not c.capacity:
             # nothing resident on device — same contract as the host plane
             return super().fetch(ids)
-        with self._lock:
+        with self._locked():
             return self._fetch_locked(ids, c)
 
     def _fetch_locked(self, ids: np.ndarray, c: FeatureCache) -> np.ndarray:
@@ -478,7 +491,7 @@ class DeviceFeaturePlane(FeaturePlane):
         c = self.cache
         if c is None or not c.capacity:
             return super().gather_aggregate(ids, neigh_idx, mode=mode)
-        with self._lock:
+        with self._locked():
             self._ensure_synced()
             slots = c.device_map[ids]
             hit = slots >= 0
@@ -518,7 +531,7 @@ class DeviceFeaturePlane(FeaturePlane):
         n = len(ids)
         if n > cap:
             raise ValueError(f"{n} input ids exceed level cap {cap}")
-        with self._lock:
+        with self._locked():
             self._ensure_synced()
             slots = c.device_map[ids]
             hit = slots >= 0
@@ -550,15 +563,15 @@ class DeviceFeaturePlane(FeaturePlane):
             return enc_dev, aux, self._dev_table
 
     def fill_rows(self, ids: np.ndarray, rows: np.ndarray):
-        with self._lock:
+        with self._locked():
             super().fill_rows(ids, rows)
 
     def _on_store_update(self, ids: np.ndarray, rows: np.ndarray):
-        with self._lock:
+        with self._locked():
             super()._on_store_update(ids, rows)
 
     def resize(self, volume_mb: float, keep_residents: bool = True):
-        with self._lock:
+        with self._locked():
             super().resize(volume_mb, keep_residents=keep_residents)
 
 

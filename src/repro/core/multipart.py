@@ -206,7 +206,6 @@ class MultiPipeline:
             agg.reissued += st.reissued
             agg.peak_batch_bytes = max(agg.peak_batch_bytes,
                                        st.peak_batch_bytes)
-            agg.queue_peak = max(agg.queue_peak, st.queue_peak)
 
 
 class MultiPartitionTrainer(TrainerCheckpointMixin, FeatureStreamConsumer):

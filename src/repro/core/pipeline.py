@@ -37,13 +37,14 @@ import queue
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, List, Optional
 
 import numpy as np
 
 from repro.core.cache import FeatureCache
 from repro.core.feature_plane import FeaturePlane, make_feature_plane
 from repro.core.sampling import NeighborSampler, MiniBatch, seed_loader
+from repro.core.spans import span
 from repro.graph.batch import generate_batch, batch_bytes
 
 _UNSET = object()
@@ -59,7 +60,6 @@ class PipelineStats:
     t_train: float = 0.0
     t_wall: float = 0.0
     peak_batch_bytes: int = 0
-    queue_peak: int = 0
     losses: List[float] = field(default_factory=list)
     accs: List[float] = field(default_factory=list)
     reissued: int = 0
@@ -80,14 +80,13 @@ class _SampleWorker(threading.Thread):
     episode-boundary ``begin_stats()`` swap takes effect immediately)."""
 
     def __init__(self, wid, sampler, pipeline, in_q, out_q, do_batchgen,
-                 heartbeat, fail_after=None):
+                 fail_after=None):
         super().__init__(daemon=True)
         self.wid = wid
         self.sampler = sampler
         self.pipe = pipeline
         self.in_q, self.out_q = in_q, out_q
         self.do_batchgen = do_batchgen
-        self.heartbeat = heartbeat
         self.fail_after = fail_after        # fault-injection for tests
         self._count = 0
 
@@ -101,23 +100,23 @@ class _SampleWorker(threading.Thread):
             try:
                 if self.fail_after is not None and self._count >= self.fail_after:
                     raise RuntimeError(f"injected failure in worker {self.wid}")
-                t0 = time.perf_counter()
-                mb = self.sampler.sample(seeds)
-                t1 = time.perf_counter()
-                if self.do_batchgen:
-                    mb = generate_batch(mb, self.pipe.plane, self.pipe.graph,
-                                        fused=self.pipe.fused)
-                t2 = time.perf_counter()
+                with span("pipeline.produce", batch=idx, worker=self.wid):
+                    t0 = time.perf_counter()
+                    mb = self.sampler.sample(seeds)
+                    t1 = time.perf_counter()
+                    if self.do_batchgen:
+                        mb = generate_batch(mb, self.pipe.plane,
+                                            self.pipe.graph,
+                                            fused=self.pipe.fused)
+                    t2 = time.perf_counter()
                 with self.pipe._lock:
                     self.pipe.stats.t_sample += t1 - t0
                     self.pipe.stats.t_batch += t2 - t1
-                self.heartbeat[self.wid] = time.time()
                 self._count += 1
                 self.out_q.put((idx, seeds, mb))
             except Exception:  # noqa: BLE001 — re-queue the work item
                 log.warning("worker %d failed on batch %d; re-issuing it",
                             self.wid, idx, exc_info=True)
-                self.heartbeat[self.wid] = -1.0   # mark dead
                 self.out_q.put((idx, seeds, None))
             finally:
                 self.in_q.task_done()
@@ -156,7 +155,6 @@ class Pipeline:
         self.batch_size = cfg.batch_size
         self.stats = PipelineStats()
         self._lock = threading.Lock()
-        self.heartbeat: Dict[int, float] = {}
         # pool state
         self._workers: List[_SampleWorker] = []
         self._in_q: Optional[queue.Queue] = None
@@ -196,7 +194,7 @@ class Pipeline:
             fa = 2 if (fail_worker is not None and w == fail_worker) else None
             wk = _SampleWorker(w, self._make_sampler(w), self,
                                self._in_q, self._out_q, do_batchgen,
-                               self.heartbeat, fail_after=fa)
+                               fail_after=fa)
             wk.start()
             self._workers.append(wk)
         self._pool_key = (do_batchgen, n)
@@ -265,11 +263,10 @@ class Pipeline:
         if self._inflight == 0:
             return False
         do_batchgen = self._pool_key[0] if self._pool_key else True
-        idx, seeds, mb = self._out_q.get()
+        with span("pipeline.wait_batch", queued=self._out_q.qsize()) as sp:
+            idx, seeds, mb = self._out_q.get()
+            sp.set_metadata(batch=idx)
         self._inflight -= 1
-        with self._lock:
-            self.stats.queue_peak = max(self.stats.queue_peak,
-                                        self._out_q.qsize())
         if mb is None:                                 # failed worker → re-issue
             if self._spare is None:
                 self._spare = self._make_sampler(997)  # straggler/failure spare

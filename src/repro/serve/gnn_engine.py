@@ -47,6 +47,7 @@ import numpy as np
 
 from repro.core.feature_plane import FeaturePlane, make_feature_plane
 from repro.core.sampling import NeighborSampler
+from repro.core.spans import host_bytes, span
 from repro.graph.batch import (generate_batch, inference_arrays,
                                compute_level_caps)
 from repro.graph.storage import Graph
@@ -191,39 +192,50 @@ class GNNInferenceEngine(EngineBase):
     def step(self) -> int:
         """One engine iteration: admit, sample, gather (through the
         plane), forward, retire.  Returns completed-request count."""
-        admit_pending(self.pending, self.running, self._try_allocate,
-                      self._on_admit)
-        if not self.running:
-            return 0
-        # one mini-batch over the ACTIVE seeds only — padding free slots
-        # with real filler nodes would push phantom traffic through the
-        # shared plane (polluting the trainer's CacheStats and, under
-        # FIFO, evicting warmed rows).  inference_arrays pads every node
-        # level to this engine's fixed caps (padded rows reference only
-        # masked −1 neighbors), so the forward has ONE jit signature no
-        # matter how many seeds are admitted or what they sample.
-        active_slots = sorted(self.running)
-        seeds = np.array([self.running[s].node for s in active_slots],
-                         dtype=np.int64)
-        if self.node_map is not None:
-            seeds = self.node_map[seeds].astype(np.int64)
-        mb = self.sampler.sample(seeds)
-        mb = generate_batch(mb, self.plane, self.graph)
-        arrays = inference_arrays(mb, level_caps=self._level_caps)
-        logits = np.asarray(self._fwd(self.params, arrays["features"],
-                                      arrays["neigh_idxs"]),
-                            dtype=np.float32)
-        now = time.perf_counter()
-        retired = 0
-        for i, slot in enumerate(active_slots):
-            req = self.running.pop(slot)
-            req.logits = logits[i].copy()
-            req.pred = int(np.argmax(req.logits))
-            req.t_done = now
-            self._retire(req)
-            retired += 1
-        self.steps += 1
-        return retired
+        with span("engine.step", step=self.steps) as sp:
+            admitted = admit_pending(self.pending, self.running,
+                                     self._try_allocate, self._on_admit)
+            # free > 0 with queued > 0: admission stopped at a same-node
+            # twin of an in-flight query while slots were free
+            sp.set_metadata(admitted=admitted, active=len(self.running),
+                            free=self.batch - len(self.running),
+                            queued=len(self.pending))
+            if not self.running:
+                return 0
+            # one mini-batch over the ACTIVE seeds only — padding free
+            # slots with real filler nodes would push phantom traffic
+            # through the shared plane (polluting the trainer's CacheStats
+            # and, under FIFO, evicting warmed rows).  inference_arrays
+            # pads every node level to this engine's fixed caps (padded
+            # rows reference only masked −1 neighbors), so the forward has
+            # ONE jit signature no matter how many seeds are admitted or
+            # what they sample.
+            active_slots = sorted(self.running)
+            seeds = np.array([self.running[s].node for s in active_slots],
+                             dtype=np.int64)
+            if self.node_map is not None:
+                seeds = self.node_map[seeds].astype(np.int64)
+            with span("engine.sample", seeds=len(seeds)):
+                mb = self.sampler.sample(seeds)
+            with span("engine.fetch", rows=len(mb.input_ids)):
+                mb = generate_batch(mb, self.plane, self.graph)
+            with span("engine.forward") as fwd:
+                arrays = inference_arrays(mb, level_caps=self._level_caps)
+                inputs = (arrays["features"], arrays["neigh_idxs"])
+                fwd.set_metadata(h2d_bytes=host_bytes(inputs))
+                logits = np.asarray(self._fwd(self.params, *inputs),
+                                    dtype=np.float32)
+            now = time.perf_counter()
+            retired = 0
+            for i, slot in enumerate(active_slots):
+                req = self.running.pop(slot)
+                req.logits = logits[i].copy()
+                req.pred = int(np.argmax(req.logits))
+                req.t_done = now
+                self._retire(req)
+                retired += 1
+            self.steps += 1
+            return retired
 
     # ------------------------------------------------------------------
     def _begin_window(self) -> Dict:
