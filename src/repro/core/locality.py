@@ -7,19 +7,32 @@ and graph density d(G), and grows as partition overlap η shrinks.
 """
 from __future__ import annotations
 
-from typing import Callable
-
 import numpy as np
 
 from repro.core.cache import FeatureCache
 
 
-def bias_weight_fn(cache: FeatureCache, gamma: float) -> Callable[[np.ndarray], np.ndarray]:
+class BiasWeights:
     """w(v) = γ if v cached else 1 (paper §III-A: higher weight → higher
-    selection probability in the weighted reservoir)."""
-    def fn(ids: np.ndarray) -> np.ndarray:
-        return np.where(cache.device_map[ids] >= 0, float(gamma), 1.0)
-    return fn
+    selection probability in the weighted reservoir).
+
+    ``min_weight``/``max_weight`` bound every weight it returns; the
+    sampler needs them to draw a hub row's picks by rejection
+    (``core/sampling.py``).  A weight function without them is sampled by
+    ES keys alone."""
+
+    def __init__(self, cache: FeatureCache, gamma: float):
+        self.cache = cache
+        self.gamma = float(gamma)
+        self.min_weight = min(1.0, self.gamma)
+        self.max_weight = max(1.0, self.gamma)
+
+    def __call__(self, ids: np.ndarray) -> np.ndarray:
+        return np.where(self.cache.device_map[ids] >= 0, self.gamma, 1.0)
+
+
+def bias_weight_fn(cache: FeatureCache, gamma: float) -> BiasWeights:
+    return BiasWeights(cache, gamma)
 
 
 def accuracy_drop_model(eta: float, gamma: float, density: float,

@@ -1,12 +1,56 @@
-"""Locality-aware sampling: Algo. 2 oracle vs vectorized ES, bias effects,
-property-based invariants."""
+"""Locality-aware sampling: Algo. 2 oracle vs vectorized ES and the hub
+rows' rejection path, bias effects, property-based invariants."""
+from types import SimpleNamespace
+
 import numpy as np
+import pytest
 from _hypothesis_compat import given, settings, st
 
+from repro.core import sampling
 from repro.core.sampling import (reservoir_sample_ref, es_sample,
                                  NeighborSampler, seed_loader)
 from repro.core.cache import FeatureCache
 from repro.core.locality import bias_weight_fn
+from repro.graph.storage import Graph
+
+
+@pytest.fixture
+def hop_spans(monkeypatch):
+    """The arguments of every ``sampler.hop`` span the sampler opens."""
+    got = []
+
+    class Rec:
+        def __init__(self, **counts):
+            self.args = dict(counts)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            got.append(self.args)
+
+        def set_metadata(self, **counts):
+            self.args.update(counts)
+
+    monkeypatch.setattr(sampling, "span",
+                        lambda name, **counts: Rec(**counts))
+    return got
+
+
+def _repeated_row_graph(nbrs: np.ndarray, rows: int) -> Graph:
+    """``rows`` nodes (ids 0..rows-1) that each have the neighbour list
+    ``nbrs`` (ids from ``rows`` up), so one hop over them is ``rows``
+    independent draws from one row."""
+    n = rows + int(nbrs.max()) + 1
+    indptr = np.zeros(n + 1, np.int64)
+    indptr[1:rows + 1] = np.arange(1, rows + 1) * len(nbrs)
+    indptr[rows + 1:] = indptr[rows]
+    indices = np.tile(nbrs + rows, rows).astype(np.int32)
+    return Graph(indptr=indptr, indices=indices,
+                 features=np.zeros((n, 1), np.float32),
+                 labels=np.zeros(n, np.int32),
+                 train_mask=np.ones(n, bool), val_mask=np.zeros(n, bool),
+                 test_mask=np.zeros(n, bool))
 
 
 def test_reservoir_returns_all_when_small():
@@ -67,8 +111,9 @@ def test_bias_increases_cached_selection(smoke_graph):
     assert frac[8.0] > frac[1.0]
 
 
-def test_gamma_one_equals_uniform(smoke_graph):
-    """γ=1 reverts to plain random sampling (same RNG → same picks)."""
+def test_gamma_one_equals_uniform(smoke_graph, hop_spans):
+    """γ=1 reverts to plain random sampling (same RNG → same picks), on
+    the ES path and the hub rows' rejection path alike."""
     cache = FeatureCache(smoke_graph, volume_mb=0.05, policy="static")
     wfn = bias_weight_fn(cache, 1.0)
     s1 = NeighborSampler(smoke_graph, (5, 5), weight_fn=wfn, seed=7)
@@ -78,6 +123,89 @@ def test_gamma_one_equals_uniform(smoke_graph):
     for blk1, blk2 in zip(b1.blocks, b2.blocks):
         assert np.array_equal(blk1.src_ids, blk2.src_ids)
         assert np.array_equal(blk1.neigh_idx, blk2.neigh_idx)
+    assert all(a["reject_rows"] > 0 for a in hop_spans)
+    assert hop_spans[:2] == hop_spans[2:]
+
+
+@pytest.mark.parametrize("gamma,dup", [(2.0, False), (8.0, False),
+                                       (2.0, True)])
+def test_rejection_and_reservoir_same_distribution(gamma, dup):
+    """A hub row's picks by rejection follow Algo. 2: selection
+    frequencies of two-class rows (``gamma`` on the first 6 ids, 1 on the
+    rest) of degree above REJECT_FACTOR · fanout · γ agree within sampling
+    noise.  With ``dup`` every cached id is a double edge: the two entries
+    are two items, so an id may be picked twice, as under Algo. 2."""
+    m = 3
+    d = int(sampling.REJECT_FACTOR * m * gamma) + 4
+    nbrs = np.arange(d)
+    if dup:
+        nbrs[6:12] = nbrs[:6]
+    cached = np.zeros(d, bool)
+    cached[:6] = True
+    rows, trials = 20_000, 4_000
+    g = _repeated_row_graph(nbrs, rows)
+    cache = SimpleNamespace(device_map=np.where(
+        np.concatenate([np.zeros(rows, bool), cached]), 0, -1))
+    s = NeighborSampler(g, (m,), weight_fn=bias_weight_fn(cache, gamma),
+                        seed=5)
+    out, hubs, proposals = s._sample_one_hop(np.arange(rows), m)
+    assert hubs == rows and proposals >= rows * m
+    f_rej = np.bincount(out.ravel() - rows, minlength=d) / (rows * m)
+    w = np.where(cached[nbrs], gamma, 1.0)
+    rng = np.random.default_rng(6)
+    f_ref = np.zeros(d)
+    for _ in range(trials):
+        np.add.at(f_ref, reservoir_sample_ref(nbrs, w, m, rng), 1)
+    f_ref /= trials * m
+    np.testing.assert_allclose(f_rej, f_ref, atol=0.012)
+    assert f_rej[:6].sum() == pytest.approx(f_ref[:6].sum(), abs=0.015)
+
+
+@pytest.mark.parametrize("gamma", [1.0, 2.0, 8.0])
+def test_rejection_picks_are_distinct_neighbours(smoke_graph, gamma):
+    """Every row past the threshold gets ``fanout`` picks, each a
+    neighbour, no position twice (an id at most as often as the row holds
+    it); rows at most ``fanout`` wide keep all their neighbours."""
+    cache = FeatureCache(smoke_graph, volume_mb=0.05, policy="static")
+    s = NeighborSampler(smoke_graph, (5,),
+                        weight_fn=bias_weight_fn(cache, gamma), seed=1)
+    dst = np.arange(smoke_graph.num_nodes)
+    out, hubs, _ = s._sample_one_hop(dst, 5)
+    assert hubs > 0
+    for v, row in zip(dst, out):
+        nb = smoke_graph.neighbors(v)
+        got = row[row >= 0]
+        assert len(got) == min(5, len(nb))
+        ids, have = np.unique(nb, return_counts=True)
+        picked, times = np.unique(got, return_counts=True)
+        assert np.isin(picked, ids).all()
+        assert (times <= have[np.searchsorted(ids, picked)]).all()
+
+
+def test_weights_without_bounds_take_es_only(smoke_graph, hop_spans,
+                                             monkeypatch):
+    """A weight function that declares no bounds samples by ES keys on
+    every row, exactly as the bounded one does with the rejection path
+    turned off."""
+    cache = FeatureCache(smoke_graph, volume_mb=0.05, policy="static")
+    bounded = bias_weight_fn(cache, 4.0)
+
+    def bare(ids):
+        return bounded(ids)
+    seeds = np.arange(64)
+    plain = NeighborSampler(smoke_graph, (5, 5), weight_fn=bare,
+                            seed=3).sample(seeds)
+    assert [a["reject_rows"] for a in hop_spans] == [0, 0]
+    assert [a["proposals"] for a in hop_spans] == [0, 0]
+    NeighborSampler(smoke_graph, (5, 5), weight_fn=bounded,
+                    seed=3).sample(seeds)
+    assert hop_spans[-1]["reject_rows"] > 0
+    monkeypatch.setattr(sampling, "REJECT_FACTOR", np.inf)
+    es = NeighborSampler(smoke_graph, (5, 5), weight_fn=bounded,
+                         seed=3).sample(seeds)
+    for a, b in zip(plain.blocks, es.blocks):
+        assert np.array_equal(a.src_ids, b.src_ids)
+        assert np.array_equal(a.neigh_idx, b.neigh_idx)
 
 
 def test_blocks_wellformed(smoke_graph):

@@ -78,13 +78,17 @@ def test_every_span_with_its_arguments(traced):
             "engine.step": {"step", "admitted", "active", "free", "queued"},
             "engine.sample": {"seeds"},
             "engine.fetch": {"rows"},
-            "engine.forward": {"h2d_bytes"}}
+            "engine.forward": {"h2d_bytes"},
+            "sampler.hop": {"rows", "reject_rows", "proposals", "picks"}}
     assert set(sp) == set(want)
     for name, keys in want.items():
         assert all(keys <= set(args) for _, _, args in sp[name]), name
     for name in ("pipeline.produce", "pipeline.wait_batch", "train.feed",
                  "train.sync"):
         assert len(sp[name]) == STEPS, name
+    # one per hop of every sampled batch, the engine's included
+    hops = len(traced["consumed"][0].blocks)
+    assert len(sp["sampler.hop"]) == hops * (STEPS + len(sp["engine.sample"]))
 
 
 def test_consumer_batches_match_produced_ones(traced):
