@@ -2,6 +2,8 @@
 compile cache, host spans, compile counting and the per-layer readers."""
 from __future__ import annotations
 
+import dataclasses
+import functools
 import gc
 import importlib.util
 import json
@@ -14,8 +16,7 @@ import traceback
 from contextlib import contextmanager, nullcontext
 from pathlib import Path
 
-BENCH = Path(__file__).resolve().parent
-ROOT = BENCH.parent
+ROOT = Path(__file__).resolve().parents[1]
 
 
 def log(msg: str) -> None:
@@ -27,22 +28,49 @@ def load_json(path: Path) -> dict:
         return json.load(f)
 
 
+@functools.lru_cache(maxsize=None)
+def load_file(path: Path):
+    """The Python module in the file ``path``, loaded once per process: a
+    model module is a static argument of the reference's jitted steps, so
+    one module object keeps their compiled programs."""
+    spec = importlib.util.spec_from_file_location(
+        "bench_" + path.stem.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def load_model(config: dict, root: Path = ROOT):
+    """The plain reference of ``config["model"]``:
+    ``bench/models/<model>.py``, which defines ``dims``, ``init_weights``,
+    ``forward`` and ``train_counts`` (``bench/README.md``)."""
+    rel = f"bench/models/{config['model']}.py"
+    if not (root / rel).is_file():
+        raise SystemExit(f"configuration {config['name']!r} names model "
+                         f"{config['model']!r}, which has no reference: "
+                         f"add {rel}")
+    return load_file(root / rel)
+
+
 def load_cell(name: str, root: Path = ROOT) -> dict:
-    """The cell ``name`` of ``BENCHMARK.json`` with its configuration,
-    traffic mix, limits and the metrics it reports."""
+    """The cell ``name`` of ``BENCHMARK.json`` with its configuration, its
+    model's reference module, traffic mix, limits and the metrics it
+    reports."""
     spec = load_json(root / "BENCHMARK.json")
     cells = {w["name"]: w for w in spec["workloads"]}
     if name not in cells:
         raise SystemExit(f"unknown workload {name!r} (known: {sorted(cells)})")
     cell = cells[name]
-    config = next(c for c in spec["configs"] if c["name"] == cell["config"])
+    entry = next(c for c in spec["configs"] if c["name"] == cell["config"])
+    config = load_json(root / entry["file"])
 
     def applies(m):
         return name in m.get("workloads", [name])
     return {
         "name": name,
         "chips": cell["chips"],
-        "config": load_json(root / config["file"]),
+        "config": config,
+        "model": load_model(config, root),
         "traffic": load_json(root / "bench" / "traffic"
                              / f"{cell['traffic']}.json"),
         "limits": load_json(root / "bench" / "limits" / f"{name}.json"),
@@ -211,48 +239,49 @@ def profiled(trace_dir: Path, on: bool):
         jax.profiler.stop_trace()
 
 
-def read_per_layer(metrics, ctx: dict) -> dict:
-    """Each per-layer metric from its reader ``bench/metrics/<name>.py``;
-    a reader that finds nothing returns None and its metric is left out."""
+def reader(name: str, root: Path = ROOT):
+    """The reader of the per-layer metric ``name``:
+    ``bench/metrics/<name>.py``, with ``read(ctx)`` and, where it reads
+    kernel times from the trace, ``KERNELS``."""
+    return load_file(root / "bench" / "metrics" / f"{name}.py")
+
+
+def kernels_read(metrics, root: Path = ROOT) -> tuple:
+    """The kernels whose device time the readers of ``metrics`` read: the
+    union of their ``KERNELS``."""
+    return tuple(sorted({k for m in metrics
+                         for k in getattr(reader(m["name"], root),
+                                          "KERNELS", ())}))
+
+
+def read_per_layer(metrics, ctx: dict, root: Path = ROOT) -> dict:
+    """Each per-layer metric from its reader; a reader that finds nothing
+    returns None and its metric is left out."""
     out = {}
     for m in metrics:
-        path = BENCH / "metrics" / f"{m['name']}.py"
-        spec = importlib.util.spec_from_file_location(
-            f"bench_metric_{m['name'].replace('.', '_')}", path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        v = mod.read(ctx)
+        v = reader(m["name"], root).read(ctx)
         if v is not None:
             out[m["name"]] = {"value": float(v), "unit": m["unit"]}
     return out
 
 
 def gnn_config(config: dict, traffic: dict):
-    """The program's config for this cell: widths from the configuration
-    file, the A3GNN knobs from the traffic mix."""
+    """The program's config for this cell: every key of the configuration
+    file that names a ``GNNConfig`` field (lists as tuples), ``lr`` from
+    its optimizer, and the A3GNN knobs from the traffic mix."""
     from repro.configs.gnn import GNNConfig
+    fields = {f.name for f in dataclasses.fields(GNNConfig)}
+    kw = {k: tuple(v) if isinstance(v, list) else v
+          for k, v in config.items() if k in fields}
     knobs = {k: traffic[k] for k in (
         "bias_rate", "cache_volume_mb", "cache_policy", "sampling_device",
         "fused_gather_agg", "workers", "parallel_mode") if k in traffic}
-    return GNNConfig(
-        name=config["name"], model=config["model"],
-        num_layers=config["num_layers"], hidden=config["hidden"],
-        feat_dim=config["feat_dim"], num_classes=config["num_classes"],
-        fanout=tuple(config["fanout"]), batch_size=config["batch_size"],
-        num_nodes=config["num_nodes"], num_edges=config["num_edges"],
-        power_exp=config["power_exp"], lr=config["optimizer"]["lr"],
-        compute_dtype=config["compute_dtype"], **knobs)
+    return GNNConfig(**{**kw, "lr": config["optimizer"]["lr"], **knobs})
 
 
 def make_graph(config: dict, arrays: dict):
     from repro.graph.storage import Graph
     return Graph(name=config["name"], **arrays)
-
-
-def dims_of(config: dict):
-    from bench.counts import sage_dims
-    return sage_dims(config["feat_dim"], config["hidden"],
-                     config["num_classes"], config["num_layers"])
 
 
 def now() -> float:

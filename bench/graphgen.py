@@ -17,8 +17,9 @@ order is one sort of ``src << 32 | position`` instead of a stable argsort.
 
 The graph is the dataset, so it comes from the config's ``dataset_seed``
 and never from the run's ``--seed``.  ``load_or_build`` keeps it under
-``bench/.cache/`` keyed by config name, dataset parameters, seed and
-``GEN_VERSION``; any change to those builds it again.
+``bench/.cache/`` keyed by the dataset parameters (``DATASET_KEYS``,
+``dataset_seed`` among them) and ``GEN_VERSION`` alone, so configurations
+of one dataset share one twin; any change to those builds another.
 """
 from __future__ import annotations
 
@@ -146,22 +147,19 @@ def dataset_params(cfg: dict) -> dict:
 
 
 def cache_key(cfg: dict) -> str:
-    blob = json.dumps({"name": cfg["name"], "gen": GEN_VERSION,
-                       **dataset_params(cfg)}, sort_keys=True)
-    return f"{cfg['name']}-{hashlib.sha256(blob.encode()).hexdigest()[:16]}"
+    blob = json.dumps({"gen": GEN_VERSION, **dataset_params(cfg)},
+                      sort_keys=True)
+    return f"twin-{hashlib.sha256(blob.encode()).hexdigest()[:16]}"
 
 
 def load_or_build(cfg: dict, cache_dir: Path = CACHE_DIR,
                   log=print) -> dict:
-    """The config's twin from ``cache_dir``, built and saved on first use.
-    Entries of other keys for the same config name are removed, so a
-    changed config leaves one graph on disk."""
+    """The config's twin from ``cache_dir``, built and saved on first use;
+    twins of other keys stay."""
     cache_dir = Path(cache_dir)
     key = cache_key(cfg)
     path = cache_dir / key
     if not (path / "done").exists():
-        for old in cache_dir.glob(f"{cfg['name']}-*"):
-            shutil.rmtree(old, ignore_errors=True)
         d = dataset_params(cfg)
         seed = d.pop("dataset_seed")
         arrays = build(seed=seed, threads=min(os.cpu_count() or 1, 8), **d)

@@ -5,6 +5,8 @@ HBM bandwidth, over the summed device time of the ``cache_gather`` ops
 in the trace, in %."""
 from bench.counts import gather_bytes
 
+KERNELS = ("cache_gather",)
+
 
 def read(ctx):
     tr = ctx.get("trace")

@@ -1,6 +1,7 @@
-"""Device step: GraphSAGE training FLOPs of the steps run inside the
-traced window (``bench/counts.py`` on each batch's real level sizes),
-over the window's length on the trace's clock and the chip's peak, in %."""
+"""Device step: training FLOPs of the steps run inside the traced window
+(the model's ``bench/models/<model>.py:train_counts`` on each batch's
+real level sizes, summed), over the window's length on the trace's clock
+and the chip's peak, in %."""
 
 
 def read(ctx):

@@ -1,12 +1,15 @@
-"""Plain float32 GraphSAGE: weights from the seed, forward, loss, AdamW.
+"""What every model's plain float32 reference shares: padding, matmuls
+at each precision, the loss and AdamW.
 
-Independent of the program: nothing here imports ``repro``.  A model is
-``L`` layers of ``h_dst @ W_self + mean(h_neighbours) @ W_neigh + b``
-(ReLU between layers) over sampled blocks: block ``i`` is an
-``(n_dst, fanout)`` matrix of row indices into the previous level, ``-1``
-for an empty slot, and the destination rows are the first ``n_dst`` rows
-of that level.  The loss is the mean softmax cross-entropy over the
-seeds; the optimizer is AdamW as the config file states it.
+Independent of the program: nothing here or under ``bench/models/``
+imports ``repro``.  A model family's own part, its weights from the
+seed, forward and counts, is ``bench/models/<model>.py`` (GraphSAGE's is
+``bench/models/graphsage.py``); the functions here take that module as
+``model``.  Blocks are sampled: block ``i`` is an ``(n_dst, fanout)``
+matrix of row indices into the previous level, ``-1`` for an empty slot,
+and the destination rows are the first ``n_dst`` rows of that level.
+The loss is the mean softmax cross-entropy over the seeds; the optimizer
+is AdamW as the config file states it.
 
 Everything runs under ``jax.default_matmul_precision("highest")``.  The
 lower-precision control (``control_mode``) keeps float32 master weights
@@ -33,29 +36,9 @@ def pow2(n: int) -> int:
     return 1 << max(int(n) - 1, 0).bit_length()
 
 
-@functools.partial(jax.jit, static_argnums=(1,))
-def _make_weights(key, dims):
-    keys = jax.random.split(key, 2 * len(dims))
-    out = []
-    for i, (din, dout) in enumerate(dims):
-        scale = 1.0 / np.sqrt(din)
-        out.append({"w_self": jax.random.normal(keys[2 * i], (din, dout))
-                    * scale,
-                    "w_neigh": jax.random.normal(keys[2 * i + 1], (din, dout))
-                    * scale,
-                    "b": jnp.zeros((dout,), jnp.float32)})
-    return {"layers": out}
-
-
-def init_weights(seed: int, dims) -> dict:
-    """The weights of ``--seed``: one jitted call on the default device,
-    float32, normal with std ``1/sqrt(din)``, zero biases, in the layout
-    ``{"layers": [{"w_self", "w_neigh", "b"}, ...]}``."""
-    return _make_weights(jax.random.PRNGKey(seed),
-                         tuple(tuple(d) for d in dims))
-
-
-def _dot(a, b, mode: str):
+def dot(a, b, mode: str):
+    """``a @ b`` in ``mode``'s precision: ``bf16x3`` is three bfloat16
+    passes, any other mode the operands' own."""
     if mode != "bf16x3":
         return a @ b
     # float32 at three bfloat16 passes (XLA's ``high``), written out so it
@@ -70,35 +53,18 @@ def _dot(a, b, mode: str):
     return a_hi @ b_hi + a_hi @ b_lo + a_lo @ b_hi
 
 
-def forward(params, feats, idxs, mode: str = "f32"):
-    """``mode``: ``f32`` (the reference), ``bf16`` (activations and weights
-    in bfloat16) or ``bf16x3`` (float32 with three-pass matmuls)."""
-    dtype = jnp.bfloat16 if mode == "bf16" else jnp.float32
-    h = feats.astype(dtype)
-    layers = params["layers"]
-    for i, (p, idx) in enumerate(zip(layers, idxs)):
-        mask = idx >= 0
-        nb = jnp.where(mask[..., None], h[jnp.maximum(idx, 0)], 0)
-        cnt = jnp.maximum(mask.sum(1, keepdims=True), 1).astype(dtype)
-        mean = nb.sum(1) / cnt
-        out = (_dot(h[:idx.shape[0]], p["w_self"].astype(dtype), mode)
-               + _dot(mean, p["w_neigh"].astype(dtype), mode)
-               + p["b"].astype(dtype))
-        h = jax.nn.relu(out) if i < len(layers) - 1 else out
-    return h.astype(jnp.float32)
-
-
-def loss_fn(params, feats, idxs, labels, mode: str = "f32"):
-    logits = forward(params, feats, idxs, mode)[:labels.shape[0]]
+def loss_fn(params, feats, idxs, labels, model, mode: str = "f32"):
+    logits = model.forward(params, feats, idxs, mode)[:labels.shape[0]]
     lse = jax.scipy.special.logsumexp(logits, axis=-1)
     gold = jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0]
     return jnp.mean(lse - gold)
 
 
-@functools.partial(jax.jit, static_argnames=("mode", "opt"))
-def _train_step(params, m, v, t, feats, idxs, labels, mode, opt):
+@functools.partial(jax.jit, static_argnames=("model", "mode", "opt"))
+def _train_step(params, m, v, t, feats, idxs, labels, model, mode, opt):
     lr, b1, b2, eps, wd = opt
-    loss, g = jax.value_and_grad(loss_fn)(params, feats, idxs, labels, mode)
+    loss, g = jax.value_and_grad(loss_fn)(params, feats, idxs, labels,
+                                          model, mode)
     m = jax.tree.map(lambda a, b: b1 * a + (1 - b1) * b, m, g)
     v = jax.tree.map(lambda a, b: b2 * a + (1 - b2) * b * b, v, g)
     bc1, bc2 = 1 - b1 ** t, 1 - b2 ** t
@@ -108,9 +74,9 @@ def _train_step(params, m, v, t, feats, idxs, labels, mode, opt):
     return params, m, v, loss, g
 
 
-@functools.partial(jax.jit, static_argnames=("mode",))
-def _forward_jit(params, feats, idxs, mode):
-    return forward(params, feats, idxs, mode)
+@functools.partial(jax.jit, static_argnames=("model", "mode"))
+def _forward_jit(params, feats, idxs, model, mode):
+    return model.forward(params, feats, idxs, mode)
 
 
 def pad_block(feats: np.ndarray, neigh: Sequence[np.ndarray],
@@ -138,10 +104,10 @@ def control_mode(config: dict) -> str:
     return "bf16x3" if config["matmul_precision"] == "highest" else "bf16"
 
 
-def train(params, batches, opt: dict, mode: str = "f32"):
-    """AdamW from ``params`` over ``batches`` of ``(feats, neigh, labels)``;
-    returns per-step losses, the first step's gradient and the final
-    parameters, all on the host."""
+def train(model, params, batches, opt: dict, mode: str = "f32"):
+    """``model``'s AdamW steps from ``params`` over ``batches`` of
+    ``(feats, neigh, labels)``; returns per-step losses, the first step's
+    gradient and the final parameters, all on the host."""
     optv = (float(opt["lr"]), float(opt["b1"]), float(opt["b2"]),
             float(opt["eps"]), float(opt["weight_decay"]))
     m = jax.tree.map(jnp.zeros_like, params)
@@ -152,16 +118,19 @@ def train(params, batches, opt: dict, mode: str = "f32"):
             f, idxs = pad_block(feats, neigh, train_pads(feats, neigh))
             params, m, v, loss, g = _train_step(
                 params, m, v, jnp.float32(t), f, idxs,
-                np.asarray(labels, np.int32), mode=mode, opt=optv)
+                np.asarray(labels, np.int32), model=model, mode=mode,
+                opt=optv)
             losses.append(float(loss))
             if g0 is None:
                 g0 = jax.tree.map(np.asarray, g)
     return losses, g0, jax.tree.map(np.asarray, params)
 
 
-def logits(params, feats, neigh, pads, mode: str = "f32") -> np.ndarray:
-    """Forward pass with every level padded to ``pads``; all rows of the
-    last level."""
+def logits(model, params, feats, neigh, pads, mode: str = "f32"
+           ) -> np.ndarray:
+    """``model``'s forward pass with every level padded to ``pads``; all
+    rows of the last level."""
     f, idxs = pad_block(feats, neigh, pads)
     with jax.default_matmul_precision("highest"):
-        return np.asarray(_forward_jit(params, f, idxs, mode=mode))
+        return np.asarray(_forward_jit(params, f, idxs, model=model,
+                                       mode=mode))

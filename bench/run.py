@@ -3,15 +3,17 @@
     python3 bench/run.py --workload sage-products.train --seed 7 \
         --seconds 40 --trace 0
 
-The cell's configuration, traffic mix, limits and metrics are found by
-name from ``BENCHMARK.json`` (see ``bench/README.md``).  The run loads
-the graph (built once per checkout under ``bench/.cache``), warms every
-shape it will use, measures for ``--seconds`` and checks what the timed
-path produced against ``bench/reference.py``.  The last line of standard
-output is one JSON object: ``correct``, ``attempted``, ``failed``,
-``metrics`` (the end-to-end metrics with ``--trace 0``, the per-layer
-ones with ``--trace 1``), ``device``, ``breakdown`` when traced, and
-``checks`` last; the checks are also the last lines of standard error.
+The cell's configuration, model reference, traffic mix, limits and
+metrics are found by name from ``BENCHMARK.json`` (see
+``bench/README.md``).  The run loads the graph (built once per checkout
+under ``bench/.cache``), warms every shape it will use, measures for
+``--seconds`` and checks what the timed path produced against the plain
+reference (``bench/reference.py`` with ``bench/models/<model>.py``).
+The last line of standard output is one JSON object: ``correct``,
+``attempted``, ``failed``, ``metrics`` (the end-to-end metrics with
+``--trace 0``, the per-layer ones with ``--trace 1``), ``device``,
+``breakdown`` when traced, and ``checks`` last; the checks are also the
+last lines of standard error.
 Without a TPU, or with fewer chips than the cell asks for, it exits 2
 and prints no result.
 
@@ -100,7 +102,7 @@ def main(argv=None, root: Path = ROOT, require_chip: bool = True,
     if traced:
         summary = tracing.summarize(
             tracing.read(tracing.latest_xplane(trace_dir)),
-            kernels=("cache_gather",))
+            kernels=common.kernels_read(cell["per_layer"], root))
         if summary is not None:
             device.update(busy_s=summary["busy_s"],
                           window_s=summary["window_s"])
@@ -108,7 +110,7 @@ def main(argv=None, root: Path = ROOT, require_chip: bool = True,
             common.log(f"[trace] {json.dumps(summary)}")
         ctx = dict(res["ctx"], trace=summary,
                    peaks=peaks.peaks(dev["kind"]))
-        metrics = common.read_per_layer(cell["per_layer"], ctx)
+        metrics = common.read_per_layer(cell["per_layer"], ctx, root)
     else:
         metrics = {m["name"]: {"value": res["end_to_end"][m["name"]],
                                "unit": m["unit"]}

@@ -6,7 +6,8 @@ and the engine's step time are per-layer metrics.
 
 Set-up builds the trainer's cache and sampler bias as the serving entry
 point does, one ``GNNInferenceEngine`` with ``slots`` slots and the
-benchmark's weights for ``--seed``, and warms the plane's gather shapes
+benchmark's weights for ``--seed`` (the cell's model module,
+``bench/models/<model>.py``), and warms the plane's gather shapes
 and the engine's one forward signature.  Arrivals are Poisson at the
 fixed ``rate_qps``: the inter-arrival gaps are one fixed draw (from
 ``arrival_seed``) that ``--seed`` puts in another order, so every seed
@@ -81,7 +82,7 @@ def setup(cell: dict, graph, seed: int, spans, fault: str = ""):
     config, traffic = cell["config"], cell["traffic"]
     cfg = common.gnn_config(config, traffic)
     tr = A3GNNTrainer(graph, cfg, seed=seed)
-    tr.params = reference.init_weights(seed, common.dims_of(config))
+    tr.params = cell["model"].init_weights(seed, config)
     eng = GNNInferenceEngine.from_trainer(tr, batch=traffic["slots"],
                                           seed=seed)
     if fault == "altered_answer":
@@ -145,11 +146,13 @@ def reference_readings(cell, graph, kept, seed, mode: str = "f32"
                        ) -> tuple:
     """Served logits and the reference's, one row per sampled request."""
     config, traffic = cell["config"], cell["traffic"]
-    params = reference.init_weights(seed, common.dims_of(config))
+    model = cell["model"]
+    params = model.init_weights(seed, config)
     pads = level_caps(traffic["slots"], config["fanout"], graph.num_nodes)
     served, ref = [], []
     for mb, _, reqs in kept:
-        out = reference.logits(params, graph.features[mb.blocks[0].src_ids],
+        out = reference.logits(model, params,
+                               graph.features[mb.blocks[0].src_ids],
                                [b.neigh_idx for b in mb.blocks], pads,
                                mode=mode)
         for r in reqs:

@@ -23,7 +23,8 @@ CONFIG.update(name="control-small", num_nodes=6000, num_edges=120000,
 def _cell(traffic: str, limits: str, **over) -> dict:
     t = json.loads((ROOT / f"bench/traffic/{traffic}.json").read_text())
     t.update(over)
-    return {"config": CONFIG, "traffic": t,
+    return {"config": CONFIG, "model": common.load_model(CONFIG),
+            "traffic": t,
             "limits": json.loads((ROOT / f"bench/limits/{limits}.json")
                                  .read_text())}
 

@@ -52,7 +52,25 @@ def test_cache_rebuilds_on_key_change(tmp_path):
     assert logs[1].startswith("[graph] loaded")
     assert all(np.array_equal(a[k], b[k]) for k in graphgen.ARRAYS)
     cfg2 = dict(cfg, dataset_seed=2)
+    assert graphgen.cache_key(cfg2) != graphgen.cache_key(cfg)
     graphgen.load_or_build(cfg2, tmp_path, log=logs.append)
     assert logs[2].startswith("[graph] built")
-    assert [p.name for p in tmp_path.iterdir()] == [graphgen.cache_key(cfg2)]
+    # the other dataset's twin stays
+    assert sorted(p.name for p in tmp_path.iterdir()) == sorted(
+        graphgen.cache_key(c) for c in (cfg, cfg2))
     json.dumps(graphgen.dataset_params(cfg2))
+
+
+def test_configs_of_one_dataset_share_a_twin(tmp_path):
+    """Two configurations that name one dataset (a GraphSAGE and another
+    family, say) load one twin: the key holds the dataset alone."""
+    sage = dict(name="tiny-sage", model="graphsage", hidden=32,
+                dataset_seed=1, degree_cap="sqrt_edges", **SMALL)
+    other = dict(sage, name="tiny-other", model="gcn", hidden=64)
+    assert graphgen.cache_key(sage) == graphgen.cache_key(other)
+    logs = []
+    a = graphgen.load_or_build(sage, tmp_path, log=logs.append)
+    b = graphgen.load_or_build(other, tmp_path, log=logs.append)
+    assert [m.split()[1] for m in logs] == ["built", "loaded"]
+    assert [p.name for p in tmp_path.iterdir()] == [graphgen.cache_key(sage)]
+    assert all(np.array_equal(a[k], b[k]) for k in graphgen.ARRAYS)

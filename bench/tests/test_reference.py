@@ -1,5 +1,6 @@
-"""bench/reference.py agrees with the program's train step and serving
-forward on one sampled batch, at a small size on the CPU."""
+"""bench/reference.py with bench/models/graphsage.py agrees with the
+program's train step and serving forward on one sampled batch, at a small
+size on the CPU."""
 import sys
 from pathlib import Path
 
@@ -19,6 +20,7 @@ CONFIG = dict(name="ref-tiny", model="graphsage", num_layers=3, hidden=32,
               compute_dtype="float32",
               optimizer={"lr": 0.003, "b1": 0.9, "b2": 0.95, "eps": 1e-8,
                          "weight_decay": 0.0})
+MODEL = common.load_model(CONFIG)
 TRAFFIC = dict(bias_rate=2.0, cache_volume_mb=0.05, cache_policy="static",
                sampling_device="cpu", parallel_mode="seq", workers=1)
 
@@ -34,7 +36,7 @@ def setup():
     graph = common.make_graph(CONFIG, arrays)
     cfg = common.gnn_config(CONFIG, TRAFFIC)
     tr = A3GNNTrainer(graph, cfg, seed=3)
-    tr.params = reference.init_weights(3, common.dims_of(CONFIG))
+    tr.params = MODEL.init_weights(3, CONFIG)
     seeds = np.flatnonzero(graph.train_mask)[:CONFIG["batch_size"]]
     mb = NeighborSampler(graph, cfg.fanout, seed=3).sample(seeds)
     return graph, tr, mb
@@ -48,8 +50,7 @@ def test_train_step_agrees(setup):
     batch = (graph.features[mb.input_ids], [b.neigh_idx for b in mb.blocks],
              graph.labels[mb.seeds])
     ref_losses, g0, p_end = reference.train(
-        reference.init_weights(3, common.dims_of(CONFIG)), [batch],
-        CONFIG["optimizer"])
+        MODEL, MODEL.init_weights(3, CONFIG), [batch], CONFIG["optimizer"])
     b1 = CONFIG["optimizer"]["b1"]
     r = checks.train_readings(
         {"losses": [loss], "params0": p0,
@@ -68,10 +69,10 @@ def test_serving_forward_agrees(setup):
     caps = [4096, 1024, 256, 64]
     arrays = inference_arrays(generate_batch(mb, None, graph),
                               level_caps=caps)
-    params = reference.init_weights(3, common.dims_of(CONFIG))
+    params = MODEL.init_weights(3, CONFIG)
     got = np.asarray(gnn_forward(params, arrays["features"],
                                  arrays["neigh_idxs"], tr.cfg))
-    want = reference.logits(params, graph.features[mb.input_ids],
+    want = reference.logits(MODEL, params, graph.features[mb.input_ids],
                             [b.neigh_idx for b in mb.blocks], caps)
     n = len(mb.seeds)
     np.testing.assert_allclose(got[:n], want[:n], rtol=1e-5, atol=1e-5)

@@ -1,11 +1,12 @@
 """Closed-loop training through the program's own trainer and pipeline.
 
 Set-up builds one ``A3GNNTrainer`` with the benchmark's weights for
-``--seed`` and one ``Pipeline`` around it, warms the plane's gather
-shapes, drives the pipeline's first ``CHECK_STEPS`` batches through the
-same ``submit``/``step`` call the window uses (those are the checked
-steps), and keeps stepping until no step compiles and the queue has
-emptied once.  The window then trains for ``--seconds``, keeping
+``--seed`` (the cell's model module, ``bench/models/<model>.py``) and
+one ``Pipeline`` around it, warms the plane's gather shapes, drives the
+pipeline's first ``CHECK_STEPS`` batches through the same
+``submit``/``step`` call the window uses (those are the checked steps),
+and keeps stepping until no step compiles and the queue has emptied
+once.  The window then trains for ``--seconds``, keeping
 ``inflight`` seed batches submitted.  After it the pipeline stops, the
 program's state is freed, and the checked steps are replayed by
 ``bench/reference.py``.
@@ -18,7 +19,7 @@ import itertools
 
 import numpy as np
 
-from bench import checks, common, counts, reference
+from bench import checks, common, reference
 
 CHECK_STEPS = 3        # steps the reference replays
 SETTLE_STEPS = 3       # quiet steps in a row that end the warm-up
@@ -147,7 +148,7 @@ def setup(cell: dict, graph, seed: int, spans, log, fault: str = ""):
     config, traffic = cell["config"], cell["traffic"]
     cfg = common.gnn_config(config, traffic)
     tr = A3GNNTrainer(graph, cfg, seed=seed)
-    tr.params = reference.init_weights(seed, common.dims_of(config))
+    tr.params = cell["model"].init_weights(seed, config)
     rng = np.random.default_rng(seed)
     keep = rng.choice(KEEP_RANGE, size=KEEP_BATCHES, replace=False)
     rec = StepRecorder(tr, CHECK_STEPS, keep, spans, fault)
@@ -244,9 +245,10 @@ def reference_readings(cell, graph, rec, seed: int, mode: str = "f32",
         neigh[-1] = neigh[-1][:n]
         batches.append((graph.features[mb.blocks[0].src_ids], neigh,
                         graph.labels[mb.seeds[:n]]))
-    params0 = reference.init_weights(seed, common.dims_of(config))
+    model = cell["model"]
+    params0 = model.init_weights(seed, config)
     losses, g0, params_end = reference.train(
-        params0, batches, config["optimizer"], mode=mode)
+        model, params0, batches, config["optimizer"], mode=mode)
     return {"losses": losses, "g0": g0, "params0": _host(params0),
             "params_end": params_end}
 
@@ -267,6 +269,18 @@ def exact_checks(graph, rec) -> dict:
         [(b.dst_ids, b.src_ids, b.neigh_idx) for b in mb.blocks])
         for mb in rec.checked)
     return {"rows_bad": float(rows), "sample_bad": float(bad)}
+
+
+def window_counts(cell, sizes) -> dict:
+    """The model's ``train_counts`` summed over the consumed steps' real
+    level sizes (``flops`` 0 where there are none)."""
+    fanouts = list(reversed(cell["config"]["fanout"]))
+    out = {"flops": 0}
+    for s in sizes:
+        for k, v in cell["model"].train_counts(s, fanouts,
+                                               cell["config"]).items():
+            out[k] = out.get(k, 0) + v
+    return out
 
 
 def free(tr, pipe) -> None:
@@ -307,18 +321,16 @@ def run(cell: dict, graph, args, compiles, log, tracing: bool,
         f"({n_win} consumed); "
         f"{in_window} compiles inside the window; reissued "
         f"{st.reissued - reissued0}")
-    fanouts = list(reversed(config["fanout"]))
-    dims = common.dims_of(config)
     start = rec.window_start
-    flops = sum(counts.sage_train_flops(s, fanouts, dims)
-                for s in rec.sizes[start:start + n_win])
-    ctx = {"t_sample": st.t_sample - st0.t_sample,
-           "t_batch": st.t_batch - st0.t_batch,
-           "t_train": st.t_train - st0.t_train, "steps_consumed": n_win,
-           "hits": cache.hits - cache0.hits,
-           "misses": cache.misses - cache0.misses,
-           "gather_rows": pipe.plane.gather_rows - rows0,
-           "feat_dim": config["feat_dim"], "flops": flops}
+    ctx = window_counts(cell, rec.sizes[start:start + n_win])
+    ctx.update({"t_sample": st.t_sample - st0.t_sample,
+                "t_batch": st.t_batch - st0.t_batch,
+                "t_train": st.t_train - st0.t_train,
+                "steps_consumed": n_win,
+                "hits": cache.hits - cache0.hits,
+                "misses": cache.misses - cache0.misses,
+                "gather_rows": pipe.plane.gather_rows - rows0,
+                "feat_dim": config["feat_dim"]})
     free(tr, pipe)
     readings = exact_checks(graph, rec)
     ref = reference_readings(cell, graph, rec, args.seed)
